@@ -54,7 +54,9 @@ def _report_skips(rep: HarvestReport) -> None:
 def _cmd_collect(args) -> int:
     rep = _harvest_many(args.ledger)
     if args.kernels:
+        from ..runtime import use_compile_cache
         from .harvest import microbench_kernels
+        use_compile_cache()
         sizes = [int(t) for t in args.sizes.split(",") if t]
         rep = rep.merged(microbench_kernels(
             sizes=sizes, repeats=args.repeats, impl=args.impl))

@@ -200,6 +200,10 @@ def microbench_kernels(*, sizes: Sequence[int] = (256, 512),
         try:
             t = _time_call(fn, *args, repeats=repeats, **kw)
         except Exception as e:  # noqa: BLE001 — one kernel failing must not
+            # sink the others off the chip; on it, a failing kernel is a
+            # fault, not a missing sample
+            if dev.platform == "tpu":
+                raise
             print(f"calibrate: microbench {op_class}{shape} failed: "
                   f"{type(e).__name__}: {e}", file=log)
             return
@@ -223,7 +227,8 @@ def microbench_kernels(*, sizes: Sequence[int] = (256, 512),
 
         w = rng.standard_normal((S, S)).astype(np.float32)
         x = jnp.asarray(rng.standard_normal((128, S)), jnp.float32)
-        bm = bn = max(32, S // 8)
+        # the TPU kernel needs 128-multiple blocks (lane-aligned x slices)
+        bm = bn = min(S, max(128, S // 8))
         keep = rng.random((S // bm, S // bn)) < 0.5
         keep[0, :] = True                       # every column keeps ≥1 block
         w_comp, idx = ops.compress_fullblock(w, keep, bm, bn)

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..runtime import compat
-
 __all__ = ["maybe_shard", "batch_axes", "spec_for_param", "tree_specs",
            "tree_shardings", "batch_spec", "cache_specs", "logits_spec",
            "filter_spec", "ShardOpts", "get_options", "set_options",
@@ -86,7 +84,7 @@ def options(**kw):
 
 
 def _mesh_axis_names() -> Tuple[str, ...]:
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     return tuple(mesh.axis_names) if not mesh.empty else ()
 
 

@@ -6,8 +6,12 @@ group) and skip that bit-serial cycle.  CIMinus profiles activations to
 estimate the skippable ratio; this kernel performs the bit-plane
 group-OR reduction over int8 activation samples.
 
-Grid: (V/TV,).  Each program reduces its vector tile to a partial count
-of skippable (vector × group × bit) slots; the wrapper sums partials.
+Grid: (V/TV,).  The wrapper hands the kernel the samples transposed,
+(K, V), so a group of ``group_rows`` inputs splits the sublane axis and
+the vectors run along the lanes.  Each program reduces its vector tile
+to a partial count of skippable (vector × group × bit) slots and writes
+it into a lane-dense (8, 128) output block; the wrapper sums partials.
+On TPU ``group_rows`` must be a multiple of 8 and ``tile_v`` of 128.
 """
 from __future__ import annotations
 
@@ -22,15 +26,15 @@ __all__ = ["bitserial_zero_profile_pallas"]
 
 def _make_kernel(group_rows: int, n_bits: int):
     def _kernel(q_ref, o_ref):
-        mag = jnp.abs(q_ref[...].astype(jnp.int32))      # (TV, Kp)
-        TV, Kp = mag.shape
-        grouped = mag.reshape(TV, Kp // group_rows, group_rows)
+        mag = jnp.abs(q_ref[...].astype(jnp.int32))      # (Kp, TV)
+        Kp, TV = mag.shape
+        grouped = mag.reshape(Kp // group_rows, group_rows, TV)
         count = jnp.zeros((), jnp.int32)
         for b in range(n_bits):
             plane = (grouped >> b) & 1
-            group_or = plane.max(axis=-1)
+            group_or = plane.max(axis=1)
             count += jnp.sum(group_or == 0, dtype=jnp.int32)
-        o_ref[0, 0] = count
+        o_ref[...] = jnp.full(o_ref.shape, count, jnp.int32)
 
     return _kernel
 
@@ -64,12 +68,12 @@ def bitserial_zero_profile_pallas(
     partials = pl.pallas_call(
         _make_kernel(group_rows, n_bits),
         grid=(Vp // TV,),
-        in_specs=[pl.BlockSpec((TV, Kp), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Vp // TV, 1), jnp.int32),
+        in_specs=[pl.BlockSpec((Kp, TV), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((None, 8, 128), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Vp // TV, 8, 128), jnp.int32),
         interpret=interpret,
-    )(q)
-    skippable = partials.sum()
+    )(q.T)
+    skippable = partials[:, 0, 0].sum()
     # padded rows contain a 1-bit in plane 0 → bits 1..7 of an all-ones pad
     # row are zero and would inflate the count; remove their contribution.
     if pad_v:

@@ -6,6 +6,11 @@ strip: grid = (M/bm,), block = (bm, N) in VMEM, output row (1, N/bn).
 
 For very wide matrices the strip splits along N as well (tile_n), with
 the partial block sums remaining exact because bn divides tile_n.
+
+The output is laid out (M/bm, 1, N/bn) so that the last two dimensions
+of each output block, (1, tile_n/bn), satisfy the TPU block rule: each
+is either the array's own extent or (with tile_n < N) a multiple of 128.
+On TPU ``bn`` must be a multiple of 128.
 """
 from __future__ import annotations
 
@@ -50,8 +55,8 @@ def block_importance_pallas(
         _make_kernel(bm, bn, criterion),
         grid=(M // bm, N // TN),
         in_specs=[pl.BlockSpec((bm, TN), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, TN // bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M // bm, N // bn), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, TN // bn), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((M // bm, 1, N // bn), jnp.float32),
         interpret=interpret,
     )(w)
-    return out
+    return out.reshape(M // bm, N // bn)

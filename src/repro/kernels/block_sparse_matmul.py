@@ -12,10 +12,13 @@ Layout (built by :func:`repro.kernels.ops.compress_fullblock`):
   L surviving K-blocks (L = max over groups, padded).
 * ``idx``:    (Gn, L) int32 — source K-block index per slot, -1 padding.
 
-Grid: (B/TB, Gn).  Each program owns one (input-row tile × output-column
-group) cell and loops its L blocks, dynamic-slicing the input from VMEM.
-``bm``/``bn`` should be multiples of the MXU tile (128) in production;
-interpret-mode tests exercise smaller shapes too.
+Grid: (B/TB, Gn, L).  ``idx`` is prefetched into SMEM as a scalar
+operand, and the input BlockSpec's index map reads it, so each step
+DMAs exactly the (TB, bm) input slice its weight block needs.  The L
+axis is the reduction: an f32 VMEM accumulator collects the partial
+products and is written out at the last slot; padding slots skip the
+matmul.  On TPU ``bm``/``bn`` must be multiples of 128 (the input slice
+is a lane block); interpret-mode tests exercise smaller shapes too.
 """
 from __future__ import annotations
 
@@ -24,25 +27,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["block_sparse_matmul_pallas"]
 
 
-def _kernel(idx_ref, x_ref, w_ref, o_ref):
-    TB = x_ref.shape[0]
-    L, bm, bn = w_ref.shape[1], w_ref.shape[2], w_ref.shape[3]
+def _kernel(idx_ref, x_ref, w_ref, o_ref, acc_ref, *, n_slots):
+    j, l = pl.program_id(1), pl.program_id(2)
 
-    def body(l, acc):
-        i = idx_ref[0, l]
-        valid = i >= 0
-        start = jnp.maximum(i, 0) * bm
-        xb = pl.load(x_ref, (slice(None), pl.dslice(start, bm)))
-        part = jnp.dot(xb, w_ref[0, l], preferred_element_type=jnp.float32)
-        return acc + jnp.where(valid, part, jnp.zeros_like(part))
+    @pl.when(l == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc = jax.lax.fori_loop(
-        0, L, body, jnp.zeros((TB, bn), jnp.float32))
-    o_ref[...] = acc.astype(o_ref.dtype)
+    @pl.when(idx_ref[j * n_slots + l] >= 0)
+    def _():
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(l == n_slots - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
@@ -63,16 +67,27 @@ def block_sparse_matmul_pallas(
     if pad_b:
         x = jnp.pad(x, ((0, pad_b), (0, 0)))
     Bp = x.shape[0]
+
+    def x_map(b, j, l, idx_ref):
+        # a padding slot re-reads block 0; its product is skipped
+        return b, jnp.maximum(idx_ref[j * L + l], 0)
+
     out = pl.pallas_call(
-        _kernel,
-        grid=(Bp // TB, Gn),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda b, j: (j, 0)),
-            pl.BlockSpec((TB, K), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, L, bm, bn), lambda b, j: (j, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((TB, bn), lambda b, j: (b, j)),
+        functools.partial(_kernel, n_slots=L),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Bp // TB, Gn, L),
+            in_specs=[
+                pl.BlockSpec((TB, bm), x_map),
+                pl.BlockSpec((None, None, bm, bn),
+                             lambda b, j, l, idx_ref: (j, l, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((TB, bn), lambda b, j, l, idx_ref: (b, j)),
+            scratch_shapes=[pltpu.VMEM((TB, bn), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((Bp, Gn * bn), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(idx, x, w_comp)
+    )(idx.reshape(-1).astype(jnp.int32), x, w_comp)
     return out[:B]

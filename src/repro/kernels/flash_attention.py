@@ -16,12 +16,13 @@ Layout / grid:
   ``lo = (q_lo − window + 1) // TK`` (window) .. ``hi = q_hi // TK``
   (causal) — a dynamic fori_loop range from the program id.
 * BlockSpec keeps the q tile + the running (m, l, acc) in VMEM; kv rows
-  stream tile-by-tile via ``pl.dslice`` loads.  TQ/TK default to the
+  stream tile-by-tile via ``pl.ds`` ref slices.  TQ/TK default to the
   MXU-aligned 128; hd is the lane dimension.  The BH grid dimension is
   squeezed out of every block (``None`` block dims) so refs are plain
-  2-D (rows, hd) tiles — no scalar indices in the load/store paths
-  (bare int indices break interpret-mode state discharge on the 0.4.x
-  jax line).
+  2-D (rows, hd) tiles.
+* The whole K/V sequence of one BH row is one block, so VMEM bounds the
+  context: on a v5e chip at hd=128 in bf16 it compiles at Skv=8192 and
+  runs out of VMEM from Skv=16384.
 
 Validated in interpret mode against the pure-jnp oracle
 (:func:`repro.kernels.ref.flash_attention_ref`) across shapes, dtypes,
@@ -62,8 +63,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, causal, window, tile_k,
     def body(ki, carry):
         m_prev, l_prev, acc_prev = carry
         start = ki * tile_k
-        kt = pl.load(k_ref, (pl.dslice(start, tile_k), slice(None)))
-        vt = pl.load(v_ref, (pl.dslice(start, tile_k), slice(None)))
+        kt = k_ref[pl.ds(start, tile_k), :]
+        vt = v_ref[pl.ds(start, tile_k), :]
         k_idx = start + jax.lax.iota(jnp.int32, tile_k)
         s = jnp.dot(q, kt.astype(jnp.float32).T,
                     preferred_element_type=jnp.float32)   # (TQ, TK)

@@ -7,8 +7,11 @@ of its *original* row — in CIM hardware this is the mux-based indexing
 unit between the pre-processor and the array (§IV-C ③); on TPU it is an
 input gather feeding a dense MXU matmul.
 
-Grid: (B/TB, N/TN).  The gather runs once per input-row tile and is
-shared across all N tiles of that row via VMEM residency.
+The gather runs once, in XLA, before the kernel: it moves B×Kc input
+elements, against the Kc×N weights the matmul reads, and the TPU has no
+lane gather inside a kernel.  The kernel is the dense matmul over the
+compressed weights.  Grid: (B/TB, N/TN); on TPU ``tile_b`` must be a
+multiple of 8 and ``tile_n`` of 128.
 """
 from __future__ import annotations
 
@@ -22,10 +25,9 @@ __all__ = ["intrablock_gather_matmul_pallas"]
 
 
 def _make_kernel(cast_f32: bool):
-    def _kernel(idx_ref, x_ref, w_ref, o_ref):
-        # x_ref: (TB, K); idx_ref: (1, Kc); w_ref: (Kc, TN); o_ref: (TB, TN)
-        xg = jnp.take(x_ref[...], idx_ref[0, :], axis=1)      # (TB, Kc)
-        w = w_ref[...]
+    def _kernel(xg_ref, w_ref, o_ref):
+        # xg_ref: (TB, Kc) gathered input; w_ref: (Kc, TN); o_ref: (TB, TN)
+        xg, w = xg_ref[...], w_ref[...]
         if cast_f32:
             # interpret-mode CPU thunks lack bf16×bf16→f32 dot support;
             # the TPU path keeps bf16 operands for native MXU accumulation
@@ -47,26 +49,25 @@ def intrablock_gather_matmul_pallas(
     tile_n: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    B, K = x.shape
+    B = x.shape[0]
     Kc, N = w_comp.shape
     TB, TN = min(tile_b, B), min(tile_n, N)
+    xg = jnp.take(x, row_idx.astype(jnp.int32), axis=1)       # (B, Kc)
     pad_b, pad_n = (-B) % TB, (-N) % TN
     if pad_b:
-        x = jnp.pad(x, ((0, pad_b), (0, 0)))
+        xg = jnp.pad(xg, ((0, pad_b), (0, 0)))
     if pad_n:
         w_comp = jnp.pad(w_comp, ((0, 0), (0, pad_n)))
-    Bp, Np = x.shape[0], w_comp.shape[1]
-    idx2 = row_idx.reshape(1, Kc).astype(jnp.int32)
+    Bp, Np = xg.shape[0], w_comp.shape[1]
     out = pl.pallas_call(
         _make_kernel(cast_f32=interpret and x.dtype == jnp.bfloat16),
         grid=(Bp // TB, Np // TN),
         in_specs=[
-            pl.BlockSpec((1, Kc), lambda b, j: (0, 0)),
-            pl.BlockSpec((TB, K), lambda b, j: (b, 0)),
+            pl.BlockSpec((TB, Kc), lambda b, j: (b, 0)),
             pl.BlockSpec((Kc, TN), lambda b, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((TB, TN), lambda b, j: (b, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), x.dtype),
         interpret=interpret,
-    )(idx2, x, w_comp)
+    )(xg, w_comp)
     return out[:B, :N]

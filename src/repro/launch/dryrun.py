@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ^ MUST precede every other import (jax locks device count on first init).
-# (No `from __future__ import annotations` here for the same reason: the
-#  XLA_FLAGS assignment must be the first statements of the module.)
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this lowers the appropriate step function (train_step /
@@ -26,6 +20,10 @@ footprint; keep it for hardware runs.
 Results append to a JSONL ledger (``--out``), one record per cell, so an
 interrupted matrix run resumes where it stopped (``--skip-done``).
 
+:func:`main` gives the CPU backend 512 devices before any backend starts
+(``jax_num_cpu_devices``); importing this module changes no flag, so a
+process on the chip can use :func:`lower_cell` and friends as they are.
+
 Usage:
   python -m repro.launch.dryrun --arch llama3-8b --cell train_4k
   python -m repro.launch.dryrun --all --mesh both --out results/dryrun.jsonl
@@ -35,6 +33,7 @@ Usage:
 import argparse
 import functools
 import json
+import os
 import re
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -51,8 +50,8 @@ from ..configs.base import ArchConfig, ShapeCell, SHAPE_CELLS
 from ..distributed import sharding as shard_rules
 from ..distributed.sharding import (batch_spec, cache_specs, spec_for_param,
                                     tree_shardings)
-from ..runtime import compat
 from ..models.transformer import decode_step, forward, init_cache, init_params, prefill
+from ..runtime import use_compile_cache
 from ..train.optimizer import AdamWConfig, adamw_init
 from ..train.step import make_train_step
 
@@ -133,7 +132,7 @@ def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
         step = make_train_step(cfg, AdamWConfig(), remat=remat,
                                microbatches=microbatches,
                                remat_policy=remat_policy)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(p_shard, o_shard, bshard),
@@ -152,7 +151,7 @@ def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
             kw = {k: v for k, v in inputs.items() if k != "tokens"}
             return prefill(params, inputs["tokens"], cfg, **kw)
 
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 prefill_fn, in_shardings=(p_shard, arg_shards),
             ).lower(params_t, specs)
@@ -172,7 +171,7 @@ def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
     def serve_step(params, tokens, cache):
         return decode_step(params, tokens, cfg, cache)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             serve_step,
             in_shardings=(p_shard, tok_shard, c_shard),
@@ -487,6 +486,8 @@ def main(argv=None) -> int:
                          "this sparsity ratio (the paper's technique in "
                          "the execution plane): d_ff → (1-r)·d_ff")
     args = ap.parse_args(argv)
+    jax.config.update("jax_num_cpu_devices", 512)
+    use_compile_cache()
 
     if args.scores_bf16:
         from ..models.layers import set_scores_dtype

@@ -7,11 +7,10 @@ Feeds the hypothesis step: "what IS the per-layer byte whale?"
 
 Usage:
   python -m repro.launch.hlo_histogram --arch llama3-8b --cell train_4k
-"""
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=256")
 
+:func:`main` gives the CPU backend 256 devices before any backend starts;
+importing this module changes no flag.
+"""
 import argparse
 import collections
 import re
@@ -22,6 +21,7 @@ import jax
 from ..configs import get_config
 from ..configs.base import SHAPE_CELLS
 from ..launch.dryrun import lower_cell, _shape_bytes
+from ..launch.mesh import make_mesh
 
 _OP_RE = re.compile(r"^\s*(?:ROOT )?[%\w.\-]+ = (.+?) ([\w\-]+)\(")
 
@@ -79,12 +79,13 @@ def main(argv=None) -> int:
     ap.add_argument("--top", type=int, default=25)
     ap.add_argument("--remat-policy", default="dots")
     args = ap.parse_args(argv)
+    jax.config.update("jax_num_cpu_devices", 256)
 
     import dataclasses
     from ..models import layers as _ly, transformer as _tf
 
     cfg = dataclasses.replace(get_config(args.arch), n_layers=args.layers)
-    mesh = jax.make_mesh((16, 16), ("data", "model"))
+    mesh = make_mesh((16, 16), ("data", "model"))
     with _tf.scan_unroll(max(2, args.layers)), _ly.chunk_unroll(8):
         low = lower_cell(cfg, SHAPE_CELLS[args.cell], mesh,
                          multi_pod=False, remat=True,

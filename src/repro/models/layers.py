@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import maybe_shard
-from ..runtime import compat
 
 Params = Dict[str, Any]
 
@@ -366,7 +365,7 @@ def _swa_seqpar_attention(x, p, cfg, mesh, *, window: int,
         return y, kc, vc
 
     wspec = P(None, None, None)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(baxes, None, None), wspec, wspec, wspec, wspec),
         out_specs=(P(baxes, None, None), P(baxes, None, None, None),
@@ -402,7 +401,7 @@ def attention_block(
 
     # sequence-parallel path: static sliding window + non-divisible heads
     # (otherwise head sharding already parallelises over "model")
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if (cache_kv is None and cross_kv is None and causal
             and isinstance(window, int) and not cfg.qk_norm
             and prefix == 0 and not mesh.empty
@@ -630,7 +629,7 @@ def _moe_block_ep(x: jnp.ndarray, p: Params, cfg, mesh, baxes) -> jnp.ndarray:
 
     gate_arg = w_gate if gated else jnp.zeros((), x.dtype)
     gate_spec = wspec_up if gated else P()
-    return compat.shard_map(
+    return jax.shard_map(
         ep_body, mesh=mesh,
         in_specs=(P(baxes, None, None), P(None, None),
                   wspec_up, wspec_dn, gate_spec),
@@ -645,7 +644,7 @@ def moe_block(x: jnp.ndarray, p: Params, cfg) -> jnp.ndarray:
     falls back to the global-dispatch path otherwise (single device /
     smoke tests)."""
     from ..distributed.sharding import get_options
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if (get_options().ep_shardmap and not mesh.empty
             and "model" in mesh.axis_names
             and cfg.n_experts % mesh.shape["model"] == 0):
@@ -687,10 +686,13 @@ def _ssd_chunked(xh, dt, A, Bm, Cm, Q):
     # bytes of the largest tensor chain with f32 kept only inside exp/cum.
     cb = jnp.einsum("bcqn,bckn->bcqk", Cq, Bq,
                     preferred_element_type=jnp.float32)  # (B,nc,Q,Q)
-    decay = jnp.exp(cum[:, :, :, None, :] - cum[:, :, None, :, :])  # (B,nc,Q,Q,H)
-    tri = jnp.tril(jnp.ones((Q, Q), bool))
-    scores = jnp.where(tri[None, None, :, :, None],
-                       cb[..., None] * decay, 0.0).astype(xh.dtype)
+    # mask before the exp: above the diagonal cum_i - cum_j ≥ 0 grows
+    # with Q and overflows f32, and a where() after the exp would pass
+    # 0·inf = NaN back through the gradient
+    tri = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
+    decay = jnp.exp(jnp.where(
+        tri, cum[:, :, :, None, :] - cum[:, :, None, :, :], -jnp.inf))
+    scores = (cb[..., None] * decay).astype(xh.dtype)  # (B,nc,Q,Q,H)
     xdt = xq * dtq[..., None]                           # (B,nc,Q,H,Pd)
     y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", scores, xdt,
                          preferred_element_type=jnp.float32)

@@ -78,7 +78,7 @@ def _deep_eqn_count(jaxpr) -> int:
 
 def _convert_jaxpr(closed, name: str) -> TraceGraph:
     """Recursively convert a (Closed)Jaxpr into a TraceGraph."""
-    from jax import core
+    from jax.extend import core
 
     jaxpr = getattr(closed, "jaxpr", closed)
     ids: Dict[object, str] = {}

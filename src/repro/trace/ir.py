@@ -10,7 +10,7 @@ HLO-text ledgers.
 A graph records only what lowering needs: per-variable shapes/dtypes,
 the equation list (primitive name + JSON-safe params), which top-level
 inputs are model parameters (``weights``: var id → parameter path), and
-nested bodies for structured primitives (``scan`` / ``pjit`` / custom
+nested bodies for structured primitives (``scan`` / ``jit`` / custom
 derivative calls).  Values, RNG keys and donation/sharding metadata are
 deliberately dropped — two traces of the same program at the same shapes
 produce byte-identical graphs, which is what makes :meth:`TraceGraph.digest`
@@ -46,7 +46,7 @@ class TraceEqn:
     """One primitive application.
 
     ``body`` holds the lowered sub-graph for structured primitives
-    (``scan``'s per-iteration jaxpr, ``pjit``'s call jaxpr, …); the
+    (``scan``'s per-iteration jaxpr, ``jit``'s call jaxpr, …); the
     trip count and const/carry splits stay in ``params`` under the
     primitive's own key names (``length`` / ``num_consts`` / …).
     """

@@ -54,9 +54,8 @@ TRANSPARENT_PRIMS = frozenset({
 
 # Structured primitives whose params carry a nested TraceGraph.
 _BODY_PRIMS = frozenset({
-    "scan", "pjit", "closed_call", "core_call", "xla_call", "remat",
-    "remat2", "checkpoint", "custom_jvp_call", "custom_vjp_call",
-    "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "while", "cond",
+    "scan", "jit", "closed_call", "remat2", "custom_jvp_call",
+    "custom_vjp_call", "while", "cond",
 })
 
 # Non-MVM kind per elementwise/reduction primitive; anything unlisted
@@ -354,7 +353,7 @@ class _Lowerer:
             for o, v in zip(eqn.outvars, outs[-len(eqn.outvars):]):
                 env[o] = v
             return
-        # pjit / custom_* / remat / cond(best branch): 1:1 arg mapping,
+        # jit / custom_* / remat / cond(best branch): 1:1 arg mapping,
         # trailing-aligned when the eqn carries extra leading operands
         # (cond's predicate, custom_vjp's fn refs)
         n = len(body.invars)

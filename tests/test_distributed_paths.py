@@ -32,7 +32,7 @@ EP_MOE = """
 import dataclasses, numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.models.layers import _moe_block_global, moe_block
-from repro.runtime import compat
+from repro.launch.mesh import make_mesh
 cfg = dataclasses.replace(get_config("qwen3-moe-30b-a3b").reduced(),
                           n_experts=8, top_k=2, capacity_factor=8.0)
 D, E, F = cfg.d_model, cfg.n_experts, cfg.d_ff
@@ -42,8 +42,8 @@ p = {"w_router": jax.random.normal(ks[0], (D, E)) * 0.1,
      "w_gate": jax.random.normal(ks[2], (E, D, F)) * 0.05,
      "w_down": jax.random.normal(ks[3], (E, F, D)) * 0.05}
 x = jax.random.normal(ks[4], (4, 16, D))
-mesh = jax.make_mesh((2, 2), ("data", "model"))
-with compat.set_mesh(mesh):
+mesh = make_mesh((2, 2), ("data", "model"))
+with jax.set_mesh(mesh):
     y_ep = jax.jit(lambda x, p: moe_block(x, p, cfg))(x, p)
     g = jax.jit(jax.grad(lambda p, x: moe_block(x, p, cfg).sum()))(p, x)
 y_ref = _moe_block_global(x, p, cfg)
@@ -56,7 +56,7 @@ SWA_SEQPAR = """
 import dataclasses, numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.models.layers import attention_block
-from repro.runtime import compat
+from repro.launch.mesh import make_mesh
 cfg = dataclasses.replace(get_config("hymba-1.5b"), d_model=80, n_heads=5,
                           n_kv_heads=5, head_dim=16, window=64)
 D, Hq, hd = 80, 5, 16
@@ -70,8 +70,8 @@ f = lambda x, p: attention_block(x, p, cfg, positions=pos, causal=True,
                                  window=cfg.window)
 y_ref, (k_ref, v_ref) = f(x, p)
 g_ref = jax.grad(lambda p, x: f(x, p)[0].sum())(p, x)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
-with compat.set_mesh(mesh):
+mesh = make_mesh((2, 2), ("data", "model"))
+with jax.set_mesh(mesh):
     y_sp, (k_sp, v_sp) = jax.jit(f)(x, p)
     g_sp = jax.jit(jax.grad(lambda p, x: f(x, p)[0].sum()))(p, x)
 assert float(jnp.abs(y_sp - y_ref).max()) < 2e-5

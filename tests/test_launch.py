@@ -109,7 +109,6 @@ def test_tiny_lower_on_local_mesh():
     """End-to-end lower+compile of a reduced arch on the local 1-device
     mesh — the same code path the 512-device dry-run exercises."""
     from repro.launch.mesh import make_local_mesh
-    from repro.runtime import compat
     from repro.train.optimizer import AdamWConfig, adamw_init
     from repro.train.step import make_train_step
     from repro.models.transformer import init_params
@@ -125,7 +124,7 @@ def test_tiny_lower_on_local_mesh():
         "labels": jax.ShapeDtypeStruct((4, 16), jnp.int32),
     }
     step = make_train_step(cfg, AdamWConfig())
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step).lower(params, opt, batch)
     compiled = lowered.compile()
     assert compiled.cost_analysis() is not None
@@ -167,3 +166,155 @@ def test_timed_execute_zeros_materialisation_local():
     assert args[0].shape == (8, 8)
     out = _timed_execute(compiled, args, repeats=2)
     assert out["execute_repeats"] == 2 and out["time_s"] > 0.0
+
+
+# ---------------------------------------------------------------------------
+# Mesh helper: Auto axes, discovery through jax.set_mesh, shard_map
+# ---------------------------------------------------------------------------
+
+def _local_mesh():
+    from repro.launch.mesh import make_local_mesh
+    return make_local_mesh()
+
+
+def test_no_mesh_is_empty():
+    m = jax.sharding.get_abstract_mesh()
+    assert m.empty
+    assert tuple(m.axis_names) == ()
+
+
+def test_set_mesh_discovery_and_restore():
+    mesh = _local_mesh()
+    assert jax.sharding.get_abstract_mesh().empty
+    with jax.set_mesh(mesh):
+        active = jax.sharding.get_abstract_mesh()
+        assert not active.empty
+        assert tuple(active.axis_names) == ("data", "model")
+        assert active.shape["model"] == 1
+        assert active.shape["data"] == jax.device_count()
+    assert jax.sharding.get_abstract_mesh().empty
+
+
+def test_set_mesh_restores_on_exception():
+    mesh = _local_mesh()
+    with pytest.raises(RuntimeError, match="boom"):
+        with jax.set_mesh(mesh):
+            raise RuntimeError("boom")
+    assert jax.sharding.get_abstract_mesh().empty
+
+
+def test_set_mesh_nesting():
+    from repro.launch.mesh import make_mesh
+    m1 = _local_mesh()
+    m2 = make_mesh((1, jax.device_count()), ("pod", "model"))
+    with jax.set_mesh(m1):
+        with jax.set_mesh(m2):
+            assert tuple(jax.sharding.get_abstract_mesh().axis_names) == \
+                ("pod", "model")
+        assert tuple(jax.sharding.get_abstract_mesh().axis_names) == \
+            ("data", "model")
+
+
+def test_make_mesh_axes_are_auto():
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_mesh
+    assert _local_mesh().axis_types == (AxisType.Auto, AxisType.Auto)
+    mesh = make_mesh((1, 1, jax.device_count()), ("pod", "data", "model"))
+    assert mesh.axis_types == (AxisType.Auto,) * 3
+
+
+def test_filter_spec_tracks_active_mesh():
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import filter_spec
+    spec = P(("pod", "data"), None, "model")
+    assert filter_spec(spec) is None              # no mesh → no-op marker
+    with jax.set_mesh(_local_mesh()):
+        assert filter_spec(spec) == P(("data",), None, "model")
+
+
+def test_maybe_shard_inside_jit_under_mesh():
+    """with_sharding_constraint with a bare PartitionSpec resolves
+    against the mesh that jax.set_mesh activated."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import maybe_shard
+
+    x = jnp.arange(8.0).reshape(4, 2)
+    f = jax.jit(lambda x: maybe_shard(x * 2, P("data", None)))
+    with jax.set_mesh(_local_mesh()):
+        y = f(x)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(x) * 2)
+    # and off-mesh it is an identity wrapper
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda x: maybe_shard(x, P("data", None)))(x)),
+        np.asarray(x))
+
+
+def test_tree_shardings_lower_with_in_shardings():
+    from jax.sharding import NamedSharding
+    from repro.distributed.sharding import tree_shardings
+    mesh = _local_mesh()
+    tree = {"wq": jnp.zeros((2, 8, 16, 8)), "b": jnp.zeros((3,))}
+    shardings = tree_shardings(mesh, tree)
+    assert all(isinstance(s, NamedSharding)
+               for s in jax.tree.leaves(shardings))
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(
+            lambda t: jax.tree.map(lambda l: l + 1, t),
+            in_shardings=(shardings,),
+        ).lower(tree)
+    assert lowered.compile() is not None
+
+
+def test_shard_map_runs_on_installed_jax():
+    from jax.sharding import PartitionSpec as P
+    mesh = _local_mesh()
+    n = jax.device_count()
+    x = jnp.arange(4 * n, dtype=jnp.float32).reshape(n, 4)
+
+    def body(xl):
+        i = jax.lax.axis_index("data")
+        return xl + i.astype(jnp.float32)
+
+    with jax.set_mesh(mesh):
+        y = jax.shard_map(
+            body, mesh=jax.sharding.get_abstract_mesh(),
+            in_specs=(P("data", None),), out_specs=P("data", None),
+            check_vma=False,
+        )(x)
+    expect = np.asarray(x) + np.arange(n)[:, None]
+    np.testing.assert_array_equal(np.asarray(y), expect)
+
+
+def test_shard_map_collective():
+    from jax.sharding import PartitionSpec as P
+    mesh = _local_mesh()
+    n = jax.device_count()
+    x = jnp.ones((n, 2), jnp.float32)
+
+    def body(xl):
+        return jax.lax.psum(xl, "data")
+
+    with jax.set_mesh(mesh):
+        y = jax.shard_map(
+            body, mesh=jax.sharding.get_abstract_mesh(),
+            in_specs=(P("data", None),), out_specs=P("data", None),
+            check_vma=False,
+        )(x)
+    np.testing.assert_array_equal(np.asarray(y), np.full((n, 2), n))
+
+
+def test_use_compile_cache_respects_environment(monkeypatch):
+    from repro import runtime
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert runtime.use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None   # left to JAX
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = runtime.use_compile_cache()
+        assert path == str(runtime.CHECKOUT_CACHE_DIR)
+        assert path.endswith(".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

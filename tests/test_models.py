@@ -85,6 +85,21 @@ def test_decode_matches_forward(arch):
                                atol=5e-4, rtol=1e-3)
 
 
+def test_ssd_gradient_finite_over_a_full_chunk():
+    """Above the diagonal the SSD decay exponent grows with the chunk
+    length and overflows f32 at 256 positions; that must not reach the
+    gradient (it made every mamba2-130m step after the first NaN)."""
+    from repro.models.layers import ssm_block
+    cfg = get_config("mamba2-130m").reduced()
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(1),
+                          (1, cfg.ssm_chunk, cfg.d_model))
+    grads = jax.grad(lambda lp: ssm_block(x, lp, cfg)[0].sum())(lp)
+    assert all(bool(jnp.all(jnp.isfinite(g)))
+               for g in jax.tree.leaves(grads))
+
+
 def test_gemma2_local_global_flags():
     from repro.models.transformer import layer_flags
     cfg = get_config("gemma2-9b")

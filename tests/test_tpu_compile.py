@@ -1,0 +1,121 @@
+"""Ahead-of-time compiles for a described TPU v5e chip (no chip needed).
+
+Interpret mode runs a Pallas kernel body in Python and so accepts block
+layouts the TPU compiler refuses (unaligned blocks, scalar stores to
+VMEM, in-kernel lane gathers).  These tests hand each kernel, and a
+two-layer qwen3-4b ``decode_step``, to the TPU compiler at qwen3-4b
+widths and check that a Mosaic kernel (``tpu_custom_call``) comes out.
+
+The topology is described inside a fixture, never at import: loading
+the TPU library holds a process-wide lock, and every xdist worker
+imports every test module.
+"""
+import dataclasses
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+
+K, N = 2560, 9728          # qwen3-4b d_model, d_ff
+HQ, HKV, HD = 32, 8, 128   # qwen3-4b heads, kv heads, head_dim
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("skv", [2048, 8192])
+def test_flash_attention_compiles(one_chip, skv):
+    q = _spec(one_chip, (1, skv, HQ, HD), jnp.bfloat16)
+    kv = _spec(one_chip, (1, skv, HKV, HD), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
+                                            impl="pallas"), q, kv, kv)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _block_sparse(s):
+    gn, L = N // 128, 10
+    return (lambda x, w, i: ops.block_sparse_matmul(x, w, i, impl="pallas"),
+            _spec(s, (256, K), jnp.bfloat16),
+            _spec(s, (gn, L, 128, 128), jnp.bfloat16),
+            _spec(s, (gn, L), jnp.int32))
+
+
+def _intrablock(s):
+    return (lambda x, w, i: ops.intrablock_gather_matmul(x, w, i,
+                                                         impl="pallas"),
+            _spec(s, (256, K), jnp.bfloat16),
+            _spec(s, (K // 2, N), jnp.bfloat16),
+            _spec(s, (K // 2,), jnp.int32))
+
+
+def _block_importance(s):
+    return (lambda w: ops.block_importance(w, 128, 128, impl="pallas"),
+            _spec(s, (K, N), jnp.bfloat16))
+
+
+def _bitserial(s):
+    return (lambda q: ops.bitserial_zero_profile(q, 128, impl="pallas"),
+            _spec(s, (1024, K), jnp.int8))
+
+
+@pytest.mark.parametrize("build", [_block_sparse, _intrablock,
+                                   _block_importance, _bitserial],
+                         ids=["block_sparse_matmul", "intrablock_gather",
+                              "block_importance", "bitserial_profile"])
+def test_sparse_kernel_compiles(one_chip, build):
+    fn, *args = build(one_chip)
+    assert "tpu_custom_call" in _compile(fn, *args).as_text()
+
+
+def test_qwen3_4b_decode_step_compiles(one_chip):
+    from repro.models.transformer import decode_step, init_cache, init_params
+    cfg = dataclasses.replace(get_config("qwen3-4b"), n_layers=2)
+    slots, max_len = 4, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_cache(cfg, slots, max_len, dtype=jnp.float32)))
+    cache["pos"] = _spec(one_chip, (slots,), jnp.int32)
+    tokens = _spec(one_chip, (slots,), jnp.int32)
+    step = jax.jit(lambda p, t, c: decode_step(p, t, cfg, c))
+    lowered = step.lower(params, tokens, cache)
+    logits, new_cache = lowered.out_info
+    assert logits.shape == (slots, cfg.vocab_size)
+    assert new_cache["k"].shape == (2, slots, max_len, HKV, HD)
+    assert lowered.compile().memory_analysis() is not None

@@ -36,11 +36,11 @@ FIXTURE_DIR = os.path.join(os.path.dirname(__file__), "fixtures", "trace")
 # digest prefixes of the committed golden graphs: capture determinism is
 # part of the contract (same program + shapes → same content key)
 FIXTURE_DIGESTS = {
-    "lm_llama3-8b_forward.json": "c812a051528c1135",
-    "lm_llama3-8b_prefill.json": "540084b77134e6a1",
-    "lm_llama3-8b_decode.json": "30fef909e3451db8",
-    "lm_dbrx-132b_forward.json": "3c6916efbbbd1f50",
-    "cnn_resnet18_32.json": "4b1d3b0245052cc8",
+    "lm_llama3-8b_forward.json": "2016002579c08628",
+    "lm_llama3-8b_prefill.json": "a84ca84c0be87312",
+    "lm_llama3-8b_decode.json": "0501a7a36bdf342c",
+    "lm_dbrx-132b_forward.json": "059e480658e1e6d7",
+    "cnn_resnet18_32.json": "3c7700ddc169e4e1",
 }
 
 
@@ -97,7 +97,7 @@ def test_llama3_forward_fixture_pinned():
     assert d["traced"]["mvm_weights"] == 743_440_384
     assert d["traced"]["mvm_weights"] == d["hand"]["mvm_weights"]
     assert d["elementwise_surplus"] == 13_459_520
-    assert traced.source_digest.startswith("c812a051528c1135")
+    assert traced.source_digest.startswith("2016002579c08628")
 
 
 def test_dbrx_moe_fixture_pinned():
