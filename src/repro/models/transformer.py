@@ -14,6 +14,11 @@ Three entry modes share one layer body:
 * ``forward``      — training / scoring over a full sequence → logits
 * ``prefill``      — forward + emit per-layer KV / SSM states → cache
 * ``decode_step``  — one token against a cache (serve_step)
+
+Named scopes mark the parts of a step in the compiled program's op
+metadata, and so in a profiler trace: ``embed``, ``layers`` (the layer
+scan) with ``attention``, ``ssm``, ``cross_attention`` and ``ffn`` inside
+each layer, and ``unembed``.  They change no numerics.
 """
 from __future__ import annotations
 
@@ -216,14 +221,18 @@ def _decoder_layer(x, lp, cfg: ArchConfig, *, positions, is_global,
     # ---- mixer(s) ----------------------------------------------------------
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if cfg.family == "hybrid":
-        ya = run_attn(h)
-        ys = run_ssm(h)
+        with jax.named_scope("attention"):
+            ya = run_attn(h)
+        with jax.named_scope("ssm"):
+            ys = run_ssm(h)
         mix = rms_norm(ya, lp["attn_branch_norm"], cfg.norm_eps) \
             + rms_norm(ys, lp["ssm_branch_norm"], cfg.norm_eps)
     elif cfg.attention == "none":
-        mix = run_ssm(h)
+        with jax.named_scope("ssm"):
+            mix = run_ssm(h)
     else:
-        mix = run_attn(h)
+        with jax.named_scope("attention"):
+            mix = run_attn(h)
     if cfg.post_norms:
         mix = rms_norm(mix, lp["post_ln1"], cfg.norm_eps)
     x = x + mix
@@ -232,16 +241,21 @@ def _decoder_layer(x, lp, cfg: ArchConfig, *, positions, is_global,
     # ---- cross-attention (whisper decoder) ----------------------------------
     if cfg.enc_dec and cross_slice is not None:
         hq = rms_norm(x, cross_slice["ln"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", hq, cross_slice["wq"]).astype(x.dtype)
-        attn = chunked_attention(q, cross_slice["k"], cross_slice["v"],
-                                 causal=False, chunk=512)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn,
+        with jax.named_scope("cross_attention"):
+            q = jnp.einsum("bsd,dhk->bshk", hq,
+                           cross_slice["wq"]).astype(x.dtype)
+            attn = chunked_attention(q, cross_slice["k"], cross_slice["v"],
+                                     causal=False, chunk=512)
+            y = jnp.einsum("bshk,hkd->bsd", attn,
                            cross_slice["wo"]).astype(x.dtype)
+        x = x + y
 
     # ---- FFN ------------------------------------------------------------------
     if cfg.d_ff > 0:
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        ff = moe_block(h2, lp, cfg) if cfg.n_experts > 1 else mlp_block(h2, lp, cfg)
+        with jax.named_scope("ffn"):
+            ff = (moe_block(h2, lp, cfg) if cfg.n_experts > 1
+                  else mlp_block(h2, lp, cfg))
         if cfg.post_norms:
             ff = rms_norm(ff, lp["post_ln2"], cfg.norm_eps)
         x = x + ff
@@ -272,20 +286,22 @@ def _cross_kv(params, enc_out, cfg: ArchConfig):
 
 
 def _embed(params, tokens, cfg: ArchConfig):
-    x = jnp.take(params["embed"], tokens, axis=0)
-    return maybe_shard(x, P(("pod", "data"), None, None))
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        return maybe_shard(x, P(("pod", "data"), None, None))
 
 
 def _unembed(params, x, cfg: ArchConfig):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"],
-                            preferred_element_type=jnp.float32)
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
-                            preferred_element_type=jnp.float32)
-    logits = softcap(logits, cfg.logit_softcap)
-    return maybe_shard(logits, P(("pod", "data"), None, "model"))
+    with jax.named_scope("unembed"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"],
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
+                                preferred_element_type=jnp.float32)
+        logits = softcap(logits, cfg.logit_softcap)
+        return maybe_shard(logits, P(("pod", "data"), None, "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -349,7 +365,8 @@ def forward(
     if remat:
         body = jax.checkpoint(body, policy=REMAT_POLICIES[remat_policy])
     xs = (params["layers"], flags) + ((cross,) if cfg.enc_dec else ())
-    x, _ = _scan(body, x, xs)
+    with jax.named_scope("layers"):
+        x, _ = _scan(body, x, xs)
     return _unembed(params, x, cfg)
 
 
@@ -404,7 +421,8 @@ def prefill(
         return x, nc
 
     xs = (params["layers"], flags) + ((cross,) if cfg.enc_dec else ())
-    x, caches = _scan(body, x, xs)
+    with jax.named_scope("layers"):
+        x, caches = _scan(body, x, xs)
     logits = _unembed(params, x[:, -1:], cfg)
 
     cache: Cache = {"pos": jnp.full((), S, jnp.int32)}
@@ -454,7 +472,8 @@ def decode_step(
                                cross_slice=cross_s, cache_len=pos)
         return x, nc
 
-    x, new_caches = _scan(body, x, tuple(xs))
+    with jax.named_scope("layers"):
+        x, new_caches = _scan(body, x, tuple(xs))
     logits = _unembed(params, x, cfg)
 
     new_cache = dict(cache)

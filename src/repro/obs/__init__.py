@@ -11,7 +11,8 @@ See ``docs/observability.md`` for the trace schema and workflows.
 """
 from .core import (OBS_SCHEMA, Heartbeat, Observer, counter, disable,
                    enable, enabled, event, get_observer, heartbeat,
-                   is_enabled, read_events, read_manifest, span)
+                   is_enabled, profiled_spans, read_events, read_manifest,
+                   set_annotator, span)
 from .energy import (component_group, component_rows, energy_table,
                      write_energy_csv, write_energy_json)
 from .metrics import ServeMetrics, StreamingHistogram
@@ -21,7 +22,7 @@ __all__ = [
     "OBS_SCHEMA", "Observer", "Heartbeat",
     "enable", "disable", "enabled", "is_enabled", "get_observer",
     "span", "counter", "event", "heartbeat",
-    "read_events", "read_manifest",
+    "read_events", "read_manifest", "set_annotator", "profiled_spans",
     "chrome_trace", "write_chrome_trace", "check_chrome_trace",
     "component_group", "component_rows", "energy_table",
     "write_energy_csv", "write_energy_json",

@@ -33,6 +33,20 @@ Enabling
   :func:`enabled` context manager);
 * ``--obs`` on the CLIs (``python -m repro.explore --obs``).
 
+Profiler bridge
+---------------
+The execution plane installs ``jax.profiler.TraceAnnotation`` as the
+*annotator* (:func:`set_annotator`; this module never imports jax).
+While a profiler runs (``annotator.is_enabled()``), every :func:`span`
+is also an annotation in the profiler's own trace, named as the span and
+carrying its attrs as stats, so the host's spans line up with the device
+operations they issue; nesting comes from the profiler's host thread.
+Such spans are also kept, newest last, in a bounded in-process record
+(:func:`profiled_spans`), so the process that ran them can join them to
+the trace it took.  With no profiler running, a span costs one
+``is_enabled()`` call and builds nothing.  The profiler being on is the
+only switch; the JSONL recording below is independent of it.
+
 Trace directory layout
 ----------------------
 ``manifest.json``      run metadata (id, argv, schema, start time)
@@ -42,17 +56,19 @@ Trace directory layout
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import sys
 import time
 from pathlib import Path
-from typing import Callable, Dict, IO, Iterator, List, Optional, Union
+from typing import (Any, Callable, Deque, Dict, IO, Iterator, List, Optional,
+                    Tuple, Union)
 
 __all__ = [
     "OBS_SCHEMA", "Observer", "enable", "disable", "enabled", "is_enabled",
     "get_observer", "span", "counter", "event", "heartbeat", "Heartbeat",
-    "read_events", "read_manifest",
+    "read_events", "read_manifest", "set_annotator", "profiled_spans",
 ]
 
 # Bump when the JSONL event shape changes incompatibly; readers
@@ -255,22 +271,58 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_obs", "_name", "_attrs", "_t0")
+# The profiler bridge (see the module docstring): a class with a static
+# ``is_enabled()``, a constructor ``(name, **attrs)``, the context-manager
+# methods and ``set_metadata(**attrs)``; None until the execution plane
+# installs one.
+_ANNOTATOR: Optional[Any] = None
+# Spans that ran under the profiler, newest last: (name, t0, t1, attrs) in
+# ``time.monotonic()`` seconds.  Bounded, so a long profiled run keeps
+# its last spans only.
+_PROFILED_MAX = 1 << 16
+_PROFILED: Deque[Tuple[str, float, float, Dict]] = collections.deque(
+    maxlen=_PROFILED_MAX)
 
-    def __init__(self, obs: Observer, name: str, attrs: Dict):
+
+def set_annotator(annotator: Optional[Any]) -> None:
+    """Install the profiler bridge (None removes it)."""
+    global _ANNOTATOR
+    _ANNOTATOR = annotator
+
+
+def profiled_spans() -> List[Tuple[str, float, float, Dict]]:
+    """The spans that ran under the profiler, oldest first, as
+    (name, t0, t1, attrs) with monotonic-clock times in seconds."""
+    return list(_PROFILED)
+
+
+class _Span:
+    __slots__ = ("_obs", "_name", "_attrs", "_t0", "_ann")
+
+    def __init__(self, obs: Optional[Observer], name: str, attrs: Dict,
+                 ann: Optional[Any] = None):
         self._obs, self._name, self._attrs = obs, name, attrs
+        self._ann = ann
         self._t0 = 0.0
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def set(self, **attrs) -> None:
         self._attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
 
     def __exit__(self, exc_type, *exc) -> None:
         t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, *exc)
+            _PROFILED.append((self._name, self._t0, t1, self._attrs))
+        if self._obs is None:
+            return
         rec = {"type": "span", "name": self._name, "t": self._t0,
                "dur_s": t1 - self._t0, "attrs": self._attrs}
         if exc_type is not None:
@@ -280,8 +332,12 @@ class _Span:
 
 def span(name: str, **attrs):
     """Time a block: ``with obs.span("explore.evaluate", arch=...)``.
-    No-op (shared null object) when disabled."""
+    No-op (shared null object) unless recording is on or a profiler
+    runs (then the span is also a profiler annotation)."""
     obs = get_observer()
+    ann = _ANNOTATOR
+    if ann is not None and ann.is_enabled():
+        return _Span(obs, name, attrs, ann(name, **attrs))
     if obs is None:
         return _NULL
     return _Span(obs, name, attrs)

@@ -6,6 +6,12 @@ recompiles).  Requests occupy slots; every engine step decodes one token
 for all active slots; finished slots (EOS or max tokens) free and refill
 from the queue.  This is the standard static-shape continuous batching
 pattern for TPU serving.
+
+While a profiler runs, the engine's ``repro.obs`` spans appear in its
+trace (``docs/observability.md``, face 4): ``serve.step`` around each
+step, ``serve.prefill`` around each request's prefill and
+``serve.decode`` around each decode, each with a ``.wait`` child where
+the host blocks on the device; the decode program is ``jit_serve_decode``.
 """
 from __future__ import annotations
 
@@ -22,8 +28,11 @@ from .. import obs
 from ..configs.base import ArchConfig
 from ..models.transformer import decode_step, forward, init_cache, prefill
 from ..obs.metrics import ServeMetrics
+from ..runtime import annotate_spans
 
 __all__ = ["Request", "ServeEngine"]
+
+annotate_spans()
 
 
 @dataclasses.dataclass
@@ -42,8 +51,10 @@ class Request:
     # "deadline"); None while healthy.  ``done`` stays False for a
     # request that never produced output.
     reject_reason: Optional[str] = None
-    # telemetry (observational only): monotonic submit time, for TTFT
+    # telemetry (observational only): monotonic submit time, for TTFT,
+    # and the engine's id for the request, which its spans carry
     submit_t: Optional[float] = None
+    req_id: Optional[int] = None
 
 
 class ServeEngine:
@@ -70,8 +81,12 @@ class ServeEngine:
         self.slot_remaining = np.zeros(slots, np.int64)
         self.slot_pos = np.zeros(slots, np.int64)     # per-slot lengths
         self.queue: List[Request] = []
-        self._decode = jax.jit(
-            lambda p, t, c: decode_step(p, t, cfg, c))
+
+        def serve_decode(p, t, c):
+            return decode_step(p, t, cfg, c)
+
+        self._decode = jax.jit(serve_decode)
+        self._submitted = 0
         self._last_tokens = np.zeros(slots, np.int32)
         # cumulative across the engine's lifetime; run() additionally
         # leaves a per-call delta in ``last_stats`` (mirroring the sweep
@@ -93,6 +108,8 @@ class ServeEngine:
             return False
         req.output = []
         req.submit_t = time.monotonic()
+        req.req_id = self._submitted
+        self._submitted += 1
         self.queue.append(req)
         self.metrics.on_submit()
         return True
@@ -101,7 +118,7 @@ class ServeEngine:
         return (req.deadline_s is not None and req.submit_t is not None
                 and now - req.submit_t > req.deadline_s)
 
-    def _fill_slots(self) -> None:
+    def _fill_slots(self) -> int:
         now = time.monotonic()
         # drop queued requests whose deadline already passed — decoding
         # them would only delay every request behind them
@@ -113,10 +130,15 @@ class ServeEngine:
             else:
                 kept.append(req)
         self.queue = kept
+        prefills = 0
         for s in range(self.slots):
             if self.slot_req[s] is None and self.queue:
                 req = self.queue.pop(0)
-                self._prefill_slot(s, req)
+                with obs.span("serve.prefill", req=req.req_id,
+                              tokens=len(req.prompt), slot=s):
+                    self._prefill_slot(s, req)
+                prefills += 1
+        return prefills
 
     def _prefill_slot(self, s: int, req: Request) -> None:
         """Per-slot prefill: run the prompt, merge its KV into the pool.
@@ -140,7 +162,8 @@ class ServeEngine:
             self.cache["ssm"] = self.cache["ssm"].at[:, s].set(pc["ssm"][:, 0])
             self.cache["conv"] = self.cache["conv"].at[:, s].set(
                 pc["conv"][:, 0].astype(self.cache["conv"].dtype))
-        tok = int(jnp.argmax(logits[0, -1]))
+        with obs.span("serve.prefill.wait"):
+            tok = int(jnp.argmax(logits[0, -1]))
         req.output.append(tok)
         self._last_tokens[s] = tok
         self.slot_req[s] = req
@@ -154,45 +177,55 @@ class ServeEngine:
     # -- decoding ------------------------------------------------------------
     def step(self) -> int:
         """Decode one token for all active slots; returns #active."""
-        t0 = time.monotonic()
-        self._fill_slots()
-        active = [s for s in range(self.slots) if self.slot_req[s] is not None]
-        if not active:
-            return 0
-        # per-slot positions: each slot decodes at its own cache length
-        self.cache["pos"] = jnp.asarray(self.slot_pos, jnp.int32)
-        tokens = jnp.asarray(self._last_tokens)
-        logits, self.cache = self._decode(self.params, tokens, self.cache)
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        completed = 0
-        now = time.monotonic()
-        for s in active:
-            req = self.slot_req[s]
-            tok = int(next_tokens[s])
-            req.output.append(tok)
-            self._last_tokens[s] = tok
-            self.slot_pos[s] += 1
-            self.slot_remaining[s] -= 1
-            if (self.slot_remaining[s] <= 0 or tok == req.eos_id
-                    or self.slot_pos[s] >= self.max_len - 1):
-                req.done = True
-                self.slot_req[s] = None
-                completed += 1
-            elif self._expired(req, now):
-                # deadline passed mid-decode: keep the partial output,
-                # free the slot for requests that can still make it
-                req.reject_reason = "deadline"
-                self.slot_req[s] = None
-                self.metrics.on_expire(queued=False)
-        step_s = time.monotonic() - t0
-        m = self.metrics
-        m.on_step(len(active), step_s)
-        m.on_tokens(len(active), step_s)
-        for _ in range(completed):
-            m.on_complete()
-        obs.counter("serve.step", len(active),
-                    queue_depth=m.queue_depth, completed=completed)
+        with obs.span("serve.step") as sp:
+            t0 = time.monotonic()
+            prefills = self._fill_slots()
+            active = [s for s in range(self.slots)
+                      if self.slot_req[s] is not None]
+            completed = self._decode_active(active) if active else 0
+            m = self.metrics
+            if active:
+                step_s = time.monotonic() - t0
+                m.on_step(len(active), step_s)
+                m.on_tokens(len(active), step_s)
+                for _ in range(completed):
+                    m.on_complete()
+            sp.set(active=len(active), queue_depth=m.queue_depth,
+                   completed=completed, prefills=prefills)
         return len(active)
+
+    def _decode_active(self, active: List[int]) -> int:
+        """One decode step for the ``active`` slots; returns how many
+        requests it completed."""
+        with obs.span("serve.decode", active=len(active)):
+            # per-slot positions: each slot decodes at its own cache length
+            self.cache["pos"] = jnp.asarray(self.slot_pos, jnp.int32)
+            tokens = jnp.asarray(self._last_tokens)
+            logits, self.cache = self._decode(self.params, tokens, self.cache)
+            with obs.span("serve.decode.wait"):
+                next_tokens = np.asarray(jnp.argmax(logits, axis=-1),
+                                         np.int32)
+            completed = 0
+            now = time.monotonic()
+            for s in active:
+                req = self.slot_req[s]
+                tok = int(next_tokens[s])
+                req.output.append(tok)
+                self._last_tokens[s] = tok
+                self.slot_pos[s] += 1
+                self.slot_remaining[s] -= 1
+                if (self.slot_remaining[s] <= 0 or tok == req.eos_id
+                        or self.slot_pos[s] >= self.max_len - 1):
+                    req.done = True
+                    self.slot_req[s] = None
+                    completed += 1
+                elif self._expired(req, now):
+                    # deadline passed mid-decode: keep the partial output,
+                    # free the slot for requests that can still make it
+                    req.reject_reason = "deadline"
+                    self.slot_req[s] = None
+                    self.metrics.on_expire(queued=False)
+        return completed
 
     def run(self) -> None:
         """Drain queue + slots; leaves this call's deltas in

@@ -138,3 +138,41 @@ def test_param_counts_near_nameplate():
     for arch, target in expect.items():
         n = get_config(arch).param_count()
         assert 0.55 * target < n < 1.45 * target, (arch, n, target)
+
+
+@pytest.mark.parametrize("arch,scopes", [
+    ("qwen3-4b", {"attention", "ffn"}),
+    ("qwen3-moe-30b-a3b", {"attention", "ffn"}),
+    ("hymba-1.5b", {"attention", "ssm", "ffn"}),
+    ("mamba2-130m", {"ssm"}),
+    ("whisper-medium", {"attention", "cross_attention", "ffn"}),
+])
+def test_decode_step_named_scopes(arch, scopes, monkeypatch):
+    """The compiled decode step names its parts in the op metadata a
+    profiler trace carries, and the scopes change no bit of the logits."""
+    import contextlib
+    import re
+    cfg = get_config(arch).reduced()
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    cache = init_cache(cfg, 2, 16, dtype=jnp.float32)
+    tokens = jnp.array([3, 5], jnp.int32)
+
+    def step(p, t, c):
+        return decode_step(p, t, cfg, c)[0]
+
+    def op_scopes(fn):
+        text = fn.lower(params, tokens, cache).compile().as_text()
+        return {part for name in re.findall(r'op_name="([^"]+)"', text)
+                for part in name.split("/")}
+
+    parts = op_scopes(jax.jit(step))
+    assert {"embed", "layers", "unembed"} | scopes <= parts
+    assert not ({"attention", "ssm", "ffn", "cross_attention"} - scopes) \
+        & parts
+    scoped = np.asarray(jax.jit(step)(params, tokens, cache))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = jax.jit(lambda p, t, c: step(p, t, c))
+    assert not scopes & op_scopes(plain)
+    np.testing.assert_array_equal(scoped, np.asarray(plain(params, tokens,
+                                                           cache)))

@@ -143,6 +143,98 @@ def test_env_auto_enable(tmp_path, monkeypatch):
         ["from.env"]
 
 
+class _FakeAnnotator:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what the
+    bridge builds and calls."""
+
+    on = False
+    built: list = []
+
+    @classmethod
+    def is_enabled(cls) -> bool:
+        return cls.on
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs, self.calls = name, dict(attrs), []
+        type(self).built.append(self)
+
+    def __enter__(self):
+        self.calls.append("enter")
+        return self
+
+    def __exit__(self, *exc):
+        self.calls.append("exit")
+
+    def set_metadata(self, **attrs):
+        self.calls.append(("set_metadata", attrs))
+
+
+@pytest.fixture
+def fake_annotator(monkeypatch):
+    import collections
+
+    import repro.obs.core as core
+    monkeypatch.setattr(_FakeAnnotator, "built", [])
+    monkeypatch.setattr(_FakeAnnotator, "on", False)
+    monkeypatch.setattr(core, "_PROFILED", collections.deque(maxlen=4))
+    monkeypatch.setattr(core, "_ANNOTATOR", None)   # restored afterwards
+    obs.set_annotator(_FakeAnnotator)
+    return _FakeAnnotator
+
+
+def test_bridge_with_no_profiler_builds_nothing(fake_annotator):
+    s1, s2 = obs.span("a"), obs.span("b", k=1)
+    assert s1 is s2                              # the shared null object
+    with s1:
+        s1.set(x=1)
+    assert fake_annotator.built == []
+    assert obs.profiled_spans() == []
+
+
+def test_bridge_with_profiler_annotates(fake_annotator):
+    fake_annotator.on = True
+    with obs.span("serve.step", k=1) as sp:
+        with obs.span("serve.decode", active=3):
+            pass
+        sp.set(done=2)
+    outer, inner = fake_annotator.built
+    assert (outer.name, outer.attrs) == ("serve.step", {"k": 1})
+    assert outer.calls == ["enter", ("set_metadata", {"done": 2}), "exit"]
+    assert (inner.name, inner.attrs) == ("serve.decode", {"active": 3})
+    assert inner.calls == ["enter", "exit"]
+    # the in-process record: innermost ends first, times nest
+    (n1, a1, b1, at1), (n2, a2, b2, at2) = obs.profiled_spans()
+    assert (n1, at1) == ("serve.decode", {"active": 3})
+    assert (n2, at2) == ("serve.step", {"k": 1, "done": 2})
+    assert a2 <= a1 <= b1 <= b2
+    # bounded: only the newest spans are kept
+    for i in range(6):
+        with obs.span("n", i=i):
+            pass
+    assert [at["i"] for _, _, _, at in obs.profiled_spans()] == [2, 3, 4, 5]
+
+
+@pytest.mark.parametrize("profiler_on", [False, True])
+def test_bridge_leaves_jsonl_records_unchanged(tmp_path, fake_annotator,
+                                               profiler_on):
+    fake_annotator.on = profiler_on
+    with obs.enabled(tmp_path / "t"):
+        with obs.span("work.block", stage="x") as sp:
+            sp.set(items=3)
+        with pytest.raises(ValueError):
+            with obs.span("boom"):
+                raise ValueError("x")
+    recs = obs.read_events(tmp_path / "t")
+    assert [(r["type"], r["name"], r["attrs"], r.get("error"))
+            for r in recs] == [
+        ("span", "work.block", {"stage": "x", "items": 3}, None),
+        ("span", "boom", {}, "ValueError")]
+    assert all(set(r) == {"type", "name", "t", "dur_s", "attrs", "pid"}
+               | ({"error"} if r["name"] == "boom" else set())
+               for r in recs)
+    assert len(fake_annotator.built) == (2 if profiler_on else 0)
+
+
 # ---------------------------------------------------------------------------
 # timeline export
 # ---------------------------------------------------------------------------

@@ -203,3 +203,87 @@ def test_deadline_cuts_off_mid_decode(params):
     snap = engine.stats_snapshot()
     assert snap["requests"]["queue_depth"] == 0
     assert snap["failures"] == {"rejected": 0, "expired": 1}
+
+
+def _profiled_run(params, tmp_path):
+    """Two requests through a 2-slot engine under the profiler, as the
+    benchmark takes a trace (host annotations only, no Python tracer);
+    returns the requests and the ``serve.`` host events as
+    (name, start_ns, end_ns, stats)."""
+    import glob
+
+    rng = np.random.default_rng(5)
+    engine = ServeEngine(CFG, params, slots=2, max_len=48)
+    reqs = [Request(prompt=rng.integers(0, CFG.vocab_size, size=n)
+                    .astype(np.int32), max_new_tokens=3) for n in (4, 7)]
+    engine.submit(reqs[0])
+    engine.step()                         # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        engine.submit(reqs[1])
+        engine.run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    events.append((e.name, e.start_ns,
+                                   e.start_ns + e.duration_ns,
+                                   dict(e.stats)))
+    return reqs, events
+
+
+def test_engine_spans_nest_in_the_profiler_trace(params, tmp_path):
+    reqs, events = _profiled_run(params, tmp_path)
+    assert all(r.done and len(r.output) == 3 for r in reqs)
+    by = {}
+    for ev in events:
+        by.setdefault(ev[0], []).append(ev)
+    assert set(by) == {"serve.step", "serve.prefill", "serve.prefill.wait",
+                       "serve.decode", "serve.decode.wait"}
+
+    def inside(child, parents):
+        return [p for p in parents if p[1] <= child[1] and child[2] <= p[2]]
+
+    for name, parent in [("serve.prefill", "serve.step"),
+                         ("serve.decode", "serve.step"),
+                         ("serve.prefill.wait", "serve.prefill"),
+                         ("serve.decode.wait", "serve.decode")]:
+        for ev in by[name]:
+            assert len(inside(ev, by[parent])) == 1, (name, ev)
+    pre, = by["serve.prefill"]            # the second request's prefill
+    assert pre[3] == {"req": reqs[1].req_id, "tokens": 7, "slot": 1}
+    assert reqs[1].req_id == 1
+    assert {ev[3]["active"] for ev in by["serve.decode"]} == {1, 2}
+    steps = by["serve.step"]
+    assert sum(ev[3]["prefills"] for ev in steps) == 1
+    assert sum(ev[3]["completed"] for ev in steps) == 2
+    assert all({"active", "queue_depth"} <= set(ev[3]) for ev in steps)
+
+
+def test_engine_builds_no_annotation_without_profiler(params, monkeypatch):
+    from repro import obs
+
+    built = []
+
+    class Spy(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **attrs):
+            built.append(name)
+            super().__init__(name, **attrs)
+
+    monkeypatch.setattr("repro.obs.core._ANNOTATOR", Spy)
+    rng = np.random.default_rng(6)
+    engine = ServeEngine(CFG, params, slots=2, max_len=48)
+    r = Request(prompt=rng.integers(0, CFG.vocab_size, size=5)
+                .astype(np.int32), max_new_tokens=3)
+    engine.submit(r)
+    n = len(obs.profiled_spans())
+    engine.run()
+    assert r.done and built == []
+    assert len(obs.profiled_spans()) == n
