@@ -1,11 +1,13 @@
 """Batched serving engine: continuous-batching-lite over a fixed slot pool.
 
-``ServeEngine`` owns a prefill function and a decode step (both jitted
-once at fixed shapes — slot count and max length — so serving never
-recompiles).  Requests occupy slots; every engine step decodes one token
-for all active slots; finished slots (EOS or max tokens) free and refill
-from the queue.  This is the standard static-shape continuous batching
-pattern for TPU serving.
+``ServeEngine`` owns a prefill program and a decode step, both jitted
+once in ``__init__``.  The decode step compiles once (slot count and
+max length); the prefill compiles once per distinct prompt length and
+writes a request's cache into its slot in place, so serving a length it
+has seen never recompiles.  Requests occupy slots; every engine step
+decodes one token for all active slots; finished slots (EOS or max
+tokens) free and refill from the queue.  This is the standard
+static-shape continuous batching pattern for TPU serving.
 
 While a profiler runs, the engine's ``repro.obs`` spans appear in its
 trace (``docs/observability.md``, face 4): ``serve.step`` around each
@@ -17,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Set
 
 import numpy as np
 
@@ -31,6 +33,10 @@ from ..obs.metrics import ServeMetrics
 from ..runtime import annotate_spans
 
 __all__ = ["Request", "ServeEngine"]
+
+# the cache entries a prefill writes into its slot's lanes; each is
+# (L, slots, ...) with the slot on axis 1
+_LANES = ("k", "v", "ssm", "conv")
 
 annotate_spans()
 
@@ -77,14 +83,32 @@ class ServeEngine:
                   strict=False, where="serve.engine")
         self.greedy = greedy
         self.cache = init_cache(cfg, slots, max_len, dtype=dtype)
+        # per-slot positions from the start, so the cache's shapes (and
+        # the prefill program's signature) never change while serving
+        self.cache["pos"] = jnp.zeros((slots,), jnp.int32)
         self.slot_req: List[Optional[Request]] = [None] * slots
         self.slot_remaining = np.zeros(slots, np.int64)
         self.slot_pos = np.zeros(slots, np.int64)     # per-slot lengths
         self.queue: List[Request] = []
 
+        def serve_prefill(p, prompt, c, slot):
+            # one program per prompt length: run the prompt, write its
+            # cache into the slot's lanes (slot traced, cache donated, so
+            # in place), return the first token
+            logits, pc = prefill(p, prompt, cfg)
+            c = dict(c)
+            for key in _LANES:
+                if key in c:
+                    start = (0, slot) + (0,) * (c[key].ndim - 2)
+                    c[key] = jax.lax.dynamic_update_slice(
+                        c[key], pc[key].astype(c[key].dtype), start)
+            return jnp.argmax(logits[0, -1]).astype(jnp.int32), c
+
         def serve_decode(p, t, c):
             return decode_step(p, t, cfg, c)
 
+        self._prefill = jax.jit(serve_prefill, donate_argnums=2)
+        self._prefilled_lengths: Set[int] = set()
         self._decode = jax.jit(serve_decode)
         self._submitted = 0
         self._last_tokens = np.zeros(slots, np.int32)
@@ -134,36 +158,27 @@ class ServeEngine:
         for s in range(self.slots):
             if self.slot_req[s] is None and self.queue:
                 req = self.queue.pop(0)
-                with obs.span("serve.prefill", req=req.req_id,
-                              tokens=len(req.prompt), slot=s):
+                n = len(req.prompt)
+                compiled = int(n not in self._prefilled_lengths)
+                with obs.span("serve.prefill", req=req.req_id, tokens=n,
+                              slot=s, compiled=compiled):
                     self._prefill_slot(s, req)
                 prefills += 1
         return prefills
 
     def _prefill_slot(self, s: int, req: Request) -> None:
-        """Per-slot prefill: run the prompt, merge its KV into the pool.
-
-        Uses a batch-1 prefill then scatters into the slot's cache lanes;
-        per-slot variable positions are tracked host-side (static shapes,
-        no recompile).
-        """
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None]
+        """Per-slot prefill: one ``serve_prefill`` call runs the prompt,
+        writes its cache into slot ``s`` and returns the first token;
+        per-slot variable positions are tracked host-side."""
+        prompt = np.asarray(req.prompt, np.int32)[None]
         S = prompt.shape[1]
         if S >= self.max_len:
             raise ValueError(f"prompt {S} ≥ max_len {self.max_len}")
-        logits, pc = prefill(self.params, prompt, self.cfg)
-        for key in ("k", "v"):
-            if key in self.cache:
-                upd = pc[key]  # (L, 1, S, H, hd)
-                self.cache[key] = jax.lax.dynamic_update_slice(
-                    self.cache[key], upd.astype(self.cache[key].dtype),
-                    (0, s, 0, 0, 0))
-        if "ssm" in self.cache:
-            self.cache["ssm"] = self.cache["ssm"].at[:, s].set(pc["ssm"][:, 0])
-            self.cache["conv"] = self.cache["conv"].at[:, s].set(
-                pc["conv"][:, 0].astype(self.cache["conv"].dtype))
+        tok, self.cache = self._prefill(self.params, prompt, self.cache,
+                                        np.int32(s))
+        self._prefilled_lengths.add(S)
         with obs.span("serve.prefill.wait"):
-            tok = int(jnp.argmax(logits[0, -1]))
+            tok = int(tok)
         req.output.append(tok)
         self._last_tokens[s] = tok
         self.slot_req[s] = req

@@ -17,20 +17,26 @@ def params():
     return init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 
 
-def _greedy_reference(params, prompt, n_new):
-    """Sequential batch-1 reference decode."""
-    toks = jnp.asarray(prompt, jnp.int32)[None]
-    logits, cache = prefill(params, toks, CFG)
-    # pad cache to engine max_len
-    pad = 64 - cache["k"].shape[2]
-    cache["k"] = jnp.pad(cache["k"], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    cache["v"] = jnp.pad(cache["v"], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+def _served_reference(cfg, params, prompt, n_new, max_len):
+    """Sequential batch-1 greedy decode of any served config: the
+    prefill's K/V (if any) padded to ``max_len``, SSM state as is."""
+    logits, cache = prefill(params, jnp.asarray(prompt, jnp.int32)[None], cfg)
+    for key in ("k", "v"):
+        if key in cache:
+            pad = max_len - cache[key].shape[2]
+            cache[key] = jnp.pad(cache[key],
+                                 ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
     out = [int(jnp.argmax(logits[0, -1]))]
     for _ in range(n_new - 1):
         lg, cache = decode_step(params, jnp.asarray([out[-1]], jnp.int32),
-                                CFG, cache)
+                                cfg, cache)
         out.append(int(jnp.argmax(lg[0])))
     return out
+
+
+def _greedy_reference(params, prompt, n_new):
+    """Sequential batch-1 reference decode."""
+    return _served_reference(CFG, params, prompt, n_new, 64)
 
 
 def test_engine_matches_reference(params):
@@ -46,6 +52,66 @@ def test_engine_matches_reference(params):
         assert r.done and len(r.output) == 6
         ref = _greedy_reference(params, p, 6)
         assert r.output == ref, (r.output, ref)
+
+
+@pytest.mark.parametrize("name", ["llama3-8b", "mamba2-130m", "hymba-1.5b"])
+def test_engine_matches_sequential_reference_per_cache_kind(name):
+    """K/V only, SSM only and hybrid: the slot is a traced index into
+    every cache entry the config has, and a refilled slot is written
+    over, so each request's tokens match its batch-1 greedy decode."""
+    cfg = get_config(name).reduced()
+    params = init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (6, 11, 6)]
+    engine = ServeEngine(cfg, params, slots=2, max_len=32)
+    reqs = [Request(prompt=p, max_new_tokens=n)
+            for p, n in zip(prompts, (4, 6, 5))]
+    for r in reqs:
+        engine.submit(r)
+    engine.run()
+    for r, p in zip(reqs, prompts):
+        assert r.done
+        assert r.output == _served_reference(cfg, params, p,
+                                             r.max_new_tokens, 32)
+
+
+def test_prefill_traces_once_per_prompt_length(params, monkeypatch,
+                                               tmp_path):
+    """The prefill program is traced once per distinct prompt length,
+    never once per slot, and the ``serve.prefill`` span's ``compiled``
+    is 1 on exactly the first request of each length."""
+    from repro import obs
+    from repro.serve import engine as engine_mod
+
+    traced = []
+    real = engine_mod.prefill
+
+    def counting(p, tokens, cfg):
+        traced.append(tokens.shape[1])
+        return real(p, tokens, cfg)
+
+    monkeypatch.setattr(engine_mod, "prefill", counting)
+    rng = np.random.default_rng(12)
+    lengths = (4, 4, 9, 4, 9, 9, 4)
+    engine = ServeEngine(CFG, params, slots=2, max_len=48)
+    reqs = [Request(prompt=rng.integers(0, CFG.vocab_size, size=n)
+                    .astype(np.int32), max_new_tokens=2 + i % 3)
+            for i, n in enumerate(lengths)]
+    for r in reqs:
+        engine.submit(r)
+    with obs.enabled(tmp_path / "obs"):
+        engine.run()
+        spans = obs.read_events(tmp_path / "obs", "serve.prefill")
+    assert all(r.done for r in reqs)
+    assert sorted(traced) == [4, 9]
+    attrs = [ev["attrs"] for ev in spans]
+    assert [a["tokens"] for a in attrs] == list(lengths)
+    assert {a["slot"] for a in attrs} == {0, 1}
+    assert [a["compiled"] for a in attrs] == [1, 0, 1, 0, 0, 0, 0]
+    for r in reqs:
+        assert r.output == _greedy_reference(params, r.prompt,
+                                             r.max_new_tokens)
 
 
 def test_more_requests_than_slots(params):
@@ -258,7 +324,8 @@ def test_engine_spans_nest_in_the_profiler_trace(params, tmp_path):
         for ev in by[name]:
             assert len(inside(ev, by[parent])) == 1, (name, ev)
     pre, = by["serve.prefill"]            # the second request's prefill
-    assert pre[3] == {"req": reqs[1].req_id, "tokens": 7, "slot": 1}
+    assert pre[3] == {"req": reqs[1].req_id, "tokens": 7, "slot": 1,
+                      "compiled": 1}
     assert reqs[1].req_id == 1
     assert {ev[3]["active"] for ev in by["serve.decode"]} == {1, 2}
     steps = by["serve.step"]
