@@ -385,14 +385,18 @@ def attention_block(
     prefix: int = 0,
     cache_kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     cache_len: Optional[jnp.ndarray] = None,
+    layer: Optional[jnp.ndarray] = None,
     cross_kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, Optional[Tuple[jnp.ndarray, jnp.ndarray]]]:
     """Full attention sub-block: projections + RoPE + chunked attention.
 
     * training/prefill: ``cache_kv=None`` → attends within ``x``.
-    * decode: ``cache_kv=(K, V)`` buffers (B, Smax, Hkv, hd) and
-      ``cache_len`` current length; new K/V are scattered in at
-      ``cache_len`` and attention spans the valid prefix.
+    * decode: ``cache_kv=(K, V)`` are every layer's buffers stacked
+      (L, B, Smax, Hkv, hd), ``layer`` this layer's index and
+      ``cache_len`` the current length; the new K/V row is written at
+      (``layer``, slot, ``cache_len``), attention spans the valid prefix
+      of this layer's block, and the updated stacks are returned.  Only
+      the new rows change, so a donated stack is updated in place.
     * cross-attention (whisper decoder): ``cross_kv`` precomputed from
       the encoder; no cache update.
     """
@@ -435,13 +439,21 @@ def attention_block(
             pos = jnp.asarray(cache_len)
             if pos.ndim == 0:
                 # uniform position: cheap dynamic_update_slice
-                K = jax.lax.dynamic_update_slice(K, k, (0, pos, 0, 0))
-                V = jax.lax.dynamic_update_slice(V, v, (0, pos, 0, 0))
+                start = (layer, 0, pos, 0, 0)
+                K = jax.lax.dynamic_update_slice(K, k[None].astype(K.dtype),
+                                                 start)
+                V = jax.lax.dynamic_update_slice(V, v[None].astype(V.dtype),
+                                                 start)
             else:
                 # per-slot positions (serving): scatter one row per batch
-                bidx = jnp.arange(K.shape[0])
-                K = K.at[bidx, pos].set(k[:, 0])
-                V = V.at[bidx, pos].set(v[:, 0])
+                bidx = jnp.arange(K.shape[1])
+                K = K.at[layer, bidx, pos].set(k[:, 0])
+                V = V.at[layer, bidx, pos].set(v[:, 0])
+            new_cache = (K, V)
+            # read this layer's block after the write: the new row is
+            # attended
+            K = jax.lax.dynamic_index_in_dim(K, layer, keepdims=False)
+            V = jax.lax.dynamic_index_in_dim(V, layer, keepdims=False)
             # q lives at absolute position cache_len; the causal mask also
             # masks the unwritten cache tail (k_idx > cache_len + S - 1).
             # Single-query decode uses ONE chunk spanning the whole cache:
@@ -452,7 +464,6 @@ def attention_block(
             out = chunked_attention(
                 q, K, V, causal=True, window=window, q_offset=cache_len,
                 attn_cap=cfg.attn_softcap, chunk=K.shape[1])
-            new_cache = (K, V)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"]).astype(x.dtype)
     return y, new_cache
 

@@ -182,17 +182,27 @@ def layer_flags(cfg: ArchConfig) -> jnp.ndarray:
 
 _BIG_WINDOW = 1 << 30
 
+# the cache entries that advance with the sequence (the rest are
+# read-only); each is (L, batch, ...)
+STATE_KEYS = ("k", "v", "ssm", "conv")
+
 
 # ---------------------------------------------------------------------------
 # Layer body (shared by train / prefill / decode)
 # ---------------------------------------------------------------------------
 
 def _decoder_layer(x, lp, cfg: ArchConfig, *, positions, is_global,
-                   mode: str, cache_slice=None, cross_slice=None,
+                   mode: str, cache=None, layer=None, cross_slice=None,
                    cache_len=None, prefix: int = 0):
-    """One decoder layer.  Returns (x, new_cache_slice)."""
+    """One decoder layer.  Returns (x, new_cache).
+
+    prefill: ``new_cache`` holds this layer's K/V and SSM states.
+    decode: ``cache`` holds every layer's entries stacked on a leading
+    L axis and ``layer`` is this layer's index; ``new_cache`` is those
+    stacks with only this layer's new entries written.
+    """
     B, S, D = x.shape
-    new_cache = {}
+    new_cache = dict(cache) if mode == "decode" else {}
     window = None
     if cfg.attention == "sliding":
         window = cfg.window
@@ -203,19 +213,26 @@ def _decoder_layer(x, lp, cfg: ArchConfig, *, positions, is_global,
         kwargs = dict(positions=positions, causal=True, window=window,
                       prefix=prefix)
         if mode == "decode":
-            kwargs.update(cache_kv=(cache_slice["k"], cache_slice["v"]),
-                          cache_len=cache_len)
+            kwargs.update(cache_kv=(cache["k"], cache["v"]),
+                          cache_len=cache_len, layer=layer)
         y, kv = attention_block(xin, lp, cfg, **kwargs)
         if kv is not None:
             new_cache["k"], new_cache["v"] = kv
         return y
 
     def run_ssm(xin):
-        state = cache_slice["ssm"] if mode == "decode" else None
-        conv = cache_slice["conv"] if mode == "decode" else None
+        state = conv = None
+        if mode == "decode":
+            state, conv = (jax.lax.dynamic_index_in_dim(cache[key], layer,
+                                                        keepdims=False)
+                           for key in ("ssm", "conv"))
         y, hT, convT = ssm_block(xin, lp, cfg, state=state, conv_state=conv)
-        if mode in ("prefill", "decode"):
+        if mode == "prefill":
             new_cache["ssm"], new_cache["conv"] = hT, convT
+        elif mode == "decode":
+            for key, new in (("ssm", hT), ("conv", convT)):
+                new_cache[key] = jax.lax.dynamic_update_index_in_dim(
+                    cache[key], new.astype(cache[key].dtype), layer, 0)
         return y
 
     # ---- mixer(s) ----------------------------------------------------------
@@ -451,33 +468,32 @@ def decode_step(
         pos if pos.ndim == 0 else pos[:, None], (B, 1))
     flags = layer_flags(cfg)
 
-    xs = [params["layers"], flags, {}]
-    per_layer_cache = {}
-    for key in ("k", "v", "ssm", "conv"):
-        if key in cache:
-            per_layer_cache[key] = cache[key]
-    xs[2] = per_layer_cache
+    # the entries a step writes ride in the scan's carry as whole stacks,
+    # with the layer index: each layer writes only its new entries, so a
+    # donated cache is updated in place; read-only cross K/V stay in xs
+    stacks = {key: cache[key] for key in STATE_KEYS if key in cache}
+    xs = [params["layers"], flags]
     if cfg.enc_dec:
-        cross_stream = {"k": cache["cross_k"], "v": cache["cross_v"],
-                        "wq": params["dec_cross"]["wq"],
-                        "wo": params["dec_cross"]["wo"],
-                        "ln": params["dec_cross"]["ln"]}
-        xs.append(cross_stream)
+        xs.append({"k": cache["cross_k"], "v": cache["cross_v"],
+                   "wq": params["dec_cross"]["wq"],
+                   "wo": params["dec_cross"]["wo"],
+                   "ln": params["dec_cross"]["ln"]})
 
-    def body(x, scanned):
-        lp, flag, cslice = scanned[0], scanned[1], scanned[2]
-        cross_s = scanned[3] if cfg.enc_dec else None
-        x, nc = _decoder_layer(x, lp, cfg, positions=positions, is_global=flag,
-                               mode="decode", cache_slice=cslice,
-                               cross_slice=cross_s, cache_len=pos)
-        return x, nc
+    def body(carry, scanned):
+        x, layer, stacks = carry
+        lp, flag = scanned[0], scanned[1]
+        cross_s = scanned[2] if cfg.enc_dec else None
+        x, stacks = _decoder_layer(x, lp, cfg, positions=positions,
+                                   is_global=flag, mode="decode",
+                                   cache=stacks, layer=layer,
+                                   cross_slice=cross_s, cache_len=pos)
+        return (x, layer + 1, stacks), None
 
     with jax.named_scope("layers"):
-        x, new_caches = _scan(body, x, tuple(xs))
+        (x, _, stacks), _ = _scan(body, (x, jnp.int32(0), stacks),
+                                  tuple(xs))
     logits = _unembed(params, x, cfg)
 
-    new_cache = dict(cache)
-    for key in new_caches:
-        new_cache[key] = new_caches[key]
+    new_cache = dict(cache, **stacks)
     new_cache["pos"] = pos + 1
     return logits[:, 0], new_cache

@@ -28,15 +28,11 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..configs.base import ArchConfig
-from ..models.transformer import decode_step, forward, init_cache, prefill
+from ..models.transformer import STATE_KEYS, decode_step, init_cache, prefill
 from ..obs.metrics import ServeMetrics
 from ..runtime import annotate_spans
 
 __all__ = ["Request", "ServeEngine"]
-
-# the cache entries a prefill writes into its slot's lanes; each is
-# (L, slots, ...) with the slot on axis 1
-_LANES = ("k", "v", "ssm", "conv")
 
 annotate_spans()
 
@@ -97,7 +93,7 @@ class ServeEngine:
             # in place), return the first token
             logits, pc = prefill(p, prompt, cfg)
             c = dict(c)
-            for key in _LANES:
+            for key in STATE_KEYS:
                 if key in c:
                     start = (0, slot) + (0,) * (c[key].ndim - 2)
                     c[key] = jax.lax.dynamic_update_slice(
@@ -105,11 +101,13 @@ class ServeEngine:
             return jnp.argmax(logits[0, -1]).astype(jnp.int32), c
 
         def serve_decode(p, t, c):
+            # the cache is donated: the step writes each slot's new row
+            # into it in place
             return decode_step(p, t, cfg, c)
 
         self._prefill = jax.jit(serve_prefill, donate_argnums=2)
         self._prefilled_lengths: Set[int] = set()
-        self._decode = jax.jit(serve_decode)
+        self._decode = jax.jit(serve_decode, donate_argnums=2)
         self._submitted = 0
         self._last_tokens = np.zeros(slots, np.int32)
         # cumulative across the engine's lifetime; run() additionally

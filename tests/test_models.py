@@ -176,3 +176,58 @@ def test_decode_step_named_scopes(arch, scopes, monkeypatch):
     assert not scopes & op_scopes(plain)
     np.testing.assert_array_equal(scoped, np.asarray(plain(params, tokens,
                                                            cache)))
+
+
+@pytest.mark.parametrize("arch,per_slot", [
+    ("llama3-8b", True), ("llama3-8b", False), ("mamba2-130m", True),
+    ("hymba-1.5b", True), ("gemma2-9b", True), ("whisper-medium", True),
+], ids=["dense-per-slot-pos", "dense-scalar-pos", "mamba2-130m",
+        "hymba-1.5b", "gemma2-9b", "whisper-medium"])
+def test_decode_step_writes_only_each_slots_new_row(arch, per_slot):
+    """From a cache whose slots were prefilled to different lengths,
+    each decode step changes only each slot's new K/V row (every other
+    row is bit-identical to its input), leaves the cross K/V untouched,
+    and gives the logits a full forward gives at that position."""
+    cfg = get_config(arch).reduced()
+    params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    # past the reduced window (32), so sliding masks take effect
+    lengths = (37, 29) if per_slot else (33, 33)
+    B, steps, max_len = len(lengths), 3, 48
+    batch = _batch_for(cfg, B, max(lengths) + steps)
+    extra = {k: batch[k] for k in ("prefix_embed", "enc_embed")
+             if k in batch}
+    full = forward(params, batch["tokens"], cfg, **extra)
+    cache = init_cache(cfg, B, max_len, dtype=jnp.float32)
+    for b, n in enumerate(lengths):
+        _, pc = prefill(params, batch["tokens"][b:b + 1, :n], cfg,
+                        **{k: v[b:b + 1] for k, v in extra.items()})
+        for key, entry in pc.items():
+            if key != "pos":
+                cache[key] = jax.lax.dynamic_update_slice(
+                    cache[key], entry, (0, b) + (0,) * (entry.ndim - 2))
+    cache["pos"] = (jnp.asarray(lengths, jnp.int32) if per_slot
+                    else jnp.int32(lengths[0]))
+    step = jax.jit(lambda p, t, c: decode_step(p, t, cfg, c))
+    pfx = cfg.prefix_len or 0
+    for i in range(steps):
+        pos = np.asarray(lengths) + i
+        tokens = batch["tokens"][np.arange(B), pos]
+        lg, new = step(params, tokens, cache)
+        np.testing.assert_allclose(
+            np.asarray(lg), np.asarray(full[np.arange(B), pfx + pos]),
+            atol=5e-4, rtol=1e-3)
+        np.testing.assert_array_equal(np.asarray(new["pos"]),
+                                      np.asarray(cache["pos"]) + 1)
+        for key in ("k", "v"):
+            if key not in cache:
+                continue
+            old, upd = np.asarray(cache[key]), np.asarray(new[key])
+            written = np.zeros(old.shape[:3], bool)
+            written[:, np.arange(B), pos] = True
+            np.testing.assert_array_equal(upd[~written], old[~written])
+            assert np.all(np.any(upd[written] != 0, axis=(-2, -1)))
+        for key in ("cross_k", "cross_v"):
+            if key in cache:
+                np.testing.assert_array_equal(np.asarray(new[key]),
+                                              np.asarray(cache[key]))
+        cache = new

@@ -76,6 +76,28 @@ def test_engine_matches_sequential_reference_per_cache_kind(name):
                                              r.max_new_tokens, 32)
 
 
+def test_decode_donates_the_cache(params):
+    """The decode program takes the engine's cache as a donated
+    argument: after a decode-only step the previous K buffer is gone
+    (the step wrote into it in place), and the tokens are unchanged."""
+    rng = np.random.default_rng(13)
+    prompts = [rng.integers(0, CFG.vocab_size, size=n).astype(np.int32)
+               for n in (7, 12)]
+    engine = ServeEngine(CFG, params, slots=2, max_len=64)
+    reqs = [Request(prompt=p, max_new_tokens=5) for p in prompts]
+    for r in reqs:
+        engine.submit(r)
+    engine.step()                       # prefills both slots, decodes
+    k = engine.cache["k"]
+    assert not engine.queue
+    engine.step()                       # decode only
+    assert k.is_deleted()
+    assert not engine.cache["k"].is_deleted()
+    engine.run()
+    for r, p in zip(reqs, prompts):
+        assert r.done and r.output == _greedy_reference(params, p, 5)
+
+
 def test_prefill_traces_once_per_prompt_length(params, monkeypatch,
                                                tmp_path):
     """The prefill program is traced once per distinct prompt length,

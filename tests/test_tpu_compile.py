@@ -12,6 +12,7 @@ imports every test module.
 """
 import dataclasses
 import os
+import re
 
 import pytest
 
@@ -119,3 +120,64 @@ def test_qwen3_4b_decode_step_compiles(one_chip):
     assert logits.shape == (slots, cfg.vocab_size)
     assert new_cache["k"].shape == (2, slots, max_len, HKV, HD)
     assert lowered.compile().memory_analysis() is not None
+
+
+_INSTR = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+
+
+def _producers(hlo, shape):
+    """(opcode, root opcode of the fused computation or None) of every
+    instruction in ``hlo`` whose result is an array of ``shape``."""
+    roots, found, comp = {}, [], None
+    for line in hlo.splitlines():
+        if line.endswith("{") and " = " not in line:
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        root, _, result, opcode, rest = m.groups()
+        if root:
+            roots[comp] = opcode
+        if result.split("{")[0] == shape:
+            calls = _CALLS.search(rest)
+            found.append((opcode, calls.group(1) if calls else None))
+    return [(op, roots.get(c) if c else None) for op, c in found]
+
+
+def test_engine_decode_writes_cache_in_place(one_chip):
+    """The engine's decode program at qwen3-4b widths (16 slots x 1024,
+    bf16 cache) aliases the donated K/V stacks to its output, needs no
+    temporary the size of a layer's block, and writes the stacks only by
+    scattering each slot's new row: no copy of a stack, and no
+    dynamic-update-slice of a whole layer block."""
+    from repro.models.transformer import init_cache, init_params
+    from repro.serve.engine import ServeEngine
+    L, slots, max_len = 2, 16, 1024
+    cfg = dataclasses.replace(get_config("qwen3-4b"), n_layers=L)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_cache(cfg, slots, max_len, dtype=jnp.bfloat16)))
+    cache["pos"] = _spec(one_chip, (slots,), jnp.int32)
+    tokens = _spec(one_chip, (slots,), jnp.int32)
+    engine = ServeEngine(cfg, params, slots=slots, max_len=max_len,
+                         dtype=jnp.bfloat16)
+    compiled = engine._decode.lower(params, tokens, cache).compile()
+    mem = compiled.memory_analysis()
+    stack = cache["k"]
+    stack_bytes = stack.size * stack.dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * stack_bytes
+    assert mem.temp_size_in_bytes < stack_bytes // L
+    shape = f"bf16[{','.join(map(str, stack.shape))}]"
+    made = _producers(compiled.as_text(), shape)
+    assert ("fusion", "scatter") in made
+    assert set(made) <= {("parameter", None), ("get-tuple-element", None),
+                         ("scatter", None), ("fusion", "scatter")}, made
