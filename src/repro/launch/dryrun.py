@@ -47,8 +47,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import obs
 from ..configs import all_configs, cells_for, get_config
 from ..configs.base import ArchConfig, ShapeCell, SHAPE_CELLS
-from ..distributed import sharding as shard_rules
-from ..distributed.sharding import (batch_spec, cache_specs, spec_for_param,
+from ..distributed.sharding import (batch_axes, cache_specs, divides,
                                     tree_shardings)
 from ..models.transformer import decode_step, forward, init_cache, init_params, prefill
 from ..runtime import use_compile_cache
@@ -101,29 +100,17 @@ def input_specs(cfg: ArchConfig, cell: ShapeCell) -> Dict[str, Any]:
 # Cell lowering
 # ---------------------------------------------------------------------------
 
-def _named(mesh, spec_tree):
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s), spec_tree,
-        is_leaf=lambda x: isinstance(x, P))
-
-
 def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
-               multi_pod: bool, remat: bool = True,
-               microbatches: int = 1, remat_policy: str = "minimal"):
+               remat: bool = True, microbatches: int = 1,
+               remat_policy: str = "minimal"):
     """Lower one cell on ``mesh``; returns the jax Lowered object."""
     params_t = param_struct(cfg)
     p_shard = tree_shardings(mesh, params_t)
-    bsp = batch_spec(multi_pod=multi_pod)
-    baxes = bsp[0]
+    baxes = batch_axes(mesh)
 
     if cell.kind == "train":
         opt_t = jax.eval_shape(adamw_init, params_t)
-        # ZeRO-1: optimizer m/v always take the fsdp=True (data-augmented)
-        # specs — they are touched once per step, so the extra gather cost
-        # is tiny next to the footprint win.
-        o_shard = tree_shardings(
-            mesh, opt_t,
-            fsdp=True if shard_rules.get_options().zero1 else None)
+        o_shard = tree_shardings(mesh, opt_t)
         specs = input_specs(cfg, cell)
         bshard = {}
         for k, v in specs["batch"].items():
@@ -159,13 +146,12 @@ def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh, *,
 
     # decode
     specs = input_specs(cfg, cell)
-    c_specs = cache_specs(cfg, cell, multi_pod=multi_pod)
+    c_specs = cache_specs(cfg, cell, mesh)
     cache_t = specs["cache"]
     c_shard = {}
     for k, v in cache_t.items():
         c_shard[k] = NamedSharding(mesh, c_specs.get(k, P()))
-    data_size = 16 * (2 if multi_pod else 1)
-    tok_spec = P(baxes) if cell.global_batch >= data_size else P(None)
+    tok_spec = P(baxes) if divides(cell.global_batch, mesh, baxes) else P(None)
     tok_shard = NamedSharding(mesh, tok_spec)
 
     def serve_step(params, tokens, cache):
@@ -320,18 +306,16 @@ def run_cell(arch: str, cell_name: str, mesh_kind: str, *,
         cfg = _dc.replace(
             cfg, d_ff=max(256, int(round(cfg.d_ff * keep / 256)) * 256))
     cell = SHAPE_CELLS[cell_name]
-    multi_pod = mesh_kind == "multi"
     from .mesh import make_production_mesh
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    n_chips = 512 if multi_pod else 256
+    mesh = make_production_mesh(multi_pod=mesh_kind == "multi")
 
     # --- full-size compile: THE dry-run proof + memory analysis -----------
     # (obs spans are no-ops unless recording is enabled; the ledger's
     # lower_s/compile_s fields below stay the source of truth)
     t0 = time.time()
     with obs.span("dryrun.lower", arch=arch, cell=cell_name, mesh=mesh_kind):
-        lowered = lower_cell(cfg, cell, mesh, multi_pod=multi_pod,
-                             remat=remat, microbatches=microbatches,
+        lowered = lower_cell(cfg, cell, mesh, remat=remat,
+                             microbatches=microbatches,
                              remat_policy=remat_policy)
     t_lower = time.time() - t0
     t0 = time.time()
@@ -367,8 +351,8 @@ def run_cell(arch: str, cell_name: str, mesh_kind: str, *,
             kw["enc_layers"] = n_layers
         cfg_l = _dc.replace(cfg, **kw)
         with _tf.scan_unroll(max(2, n_layers)), _ly.chunk_unroll(8):
-            low = lower_cell(cfg_l, cell, mesh, multi_pod=multi_pod,
-                             remat=remat, microbatches=microbatches,
+            low = lower_cell(cfg_l, cell, mesh, remat=remat,
+                             microbatches=microbatches,
                              remat_policy=remat_policy)
             return _cost_of(low.compile())
 
@@ -386,7 +370,7 @@ def run_cell(arch: str, cell_name: str, mesh_kind: str, *,
         "cell": cell_name,
         "mesh": mesh_kind,
         "tag": extra_tag,
-        "chips": n_chips,
+        "chips": mesh.size,
         "kind": cell.kind,
         "seq_len": cell.seq_len,
         "global_batch": cell.global_batch,
@@ -465,22 +449,9 @@ def main(argv=None) -> int:
                          "under <out dir>/trace/, and stamp its content "
                          "digest + MVM totals into the ledger record")
     ap.add_argument("--tag", default="")
-    # sharding-strategy knobs (§Perf hillclimb)
-    ap.add_argument("--fsdp", action="store_true",
-                    help="shard params over 'data' too (FSDP/ZeRO-3)")
-    ap.add_argument("--no-zero1", action="store_true",
-                    help="disable ZeRO-1 optimizer-state sharding")
-    ap.add_argument("--no-ep", action="store_true",
-                    help="disable shard_map expert parallelism")
-    ap.add_argument("--legacy-sharding", action="store_true",
-                    help="legacy head_dim attention fallback sharding")
     ap.add_argument("--remat-policy", default="minimal",
                     choices=["minimal", "dots", "nothing"],
                     help="activation-checkpoint policy for train cells")
-    ap.add_argument("--scores-bf16", action="store_true",
-                    help="materialise attention score tiles in bf16 "
-                         "(approximates the fused Pallas flash kernel's "
-                         "HBM behaviour)")
     ap.add_argument("--ffn-compress", type=float, default=0.0,
                     help="execute with FullBlock row-compressed FFN at "
                          "this sparsity ratio (the paper's technique in "
@@ -488,19 +459,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     jax.config.update("jax_num_cpu_devices", 512)
     use_compile_cache()
-
-    if args.scores_bf16:
-        from ..models.layers import set_scores_dtype
-        set_scores_dtype(jnp.bfloat16)
-
-    shard_rules.set_options(
-        fsdp=args.fsdp,
-        # ZeRO-1 rides with FSDP (matched layouts); standalone ZeRO-1
-        # triggers GSPMD replicate-then-partition resharding (§Perf)
-        zero1=args.fsdp and not args.no_zero1,
-        ep_shardmap=not args.no_ep,
-        attn_kv_fallback="head_dim" if args.legacy_sharding else "replicate",
-    )
 
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     done = set()

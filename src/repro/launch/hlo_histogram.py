@@ -21,7 +21,7 @@ import jax
 from ..configs import get_config
 from ..configs.base import SHAPE_CELLS
 from ..launch.dryrun import lower_cell, _shape_bytes
-from ..launch.mesh import make_mesh
+from ..launch.mesh import make_production_mesh
 
 _OP_RE = re.compile(r"^\s*(?:ROOT )?[%\w.\-]+ = (.+?) ([\w\-]+)\(")
 
@@ -85,10 +85,9 @@ def main(argv=None) -> int:
     from ..models import layers as _ly, transformer as _tf
 
     cfg = dataclasses.replace(get_config(args.arch), n_layers=args.layers)
-    mesh = make_mesh((16, 16), ("data", "model"))
+    mesh = make_production_mesh()
     with _tf.scan_unroll(max(2, args.layers)), _ly.chunk_unroll(8):
-        low = lower_cell(cfg, SHAPE_CELLS[args.cell], mesh,
-                         multi_pod=False, remat=True,
+        low = lower_cell(cfg, SHAPE_CELLS[args.cell], mesh, remat=True,
                          remat_policy=args.remat_policy)
         compiled = low.compile()
     hist = histogram(compiled.as_text(), args.top)

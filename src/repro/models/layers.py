@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.sharding import maybe_shard
+from ..distributed.sharding import batch_axes, divides, maybe_shard
 
 Params = Dict[str, Any]
 
@@ -28,26 +28,6 @@ Params = Dict[str, Any]
 # bytes (a rolled scan body is counted once).  Enabled only by the
 # dry-run's per-layer measurement variants via ``chunk_unroll``.
 _CHUNK_UNROLL: int = 1
-
-# A/B switch for the statically tiled attention path (perf ablations).
-_TILED_ATTN: bool = True
-
-
-def set_tiled_attn(on: bool) -> None:
-    global _TILED_ATTN
-    _TILED_ATTN = on
-
-
-# Materialisation dtype for attention score tiles.  f32 (default) is the
-# exact-softmax configuration; bf16 approximates what the fused Pallas
-# flash kernel does on TPU (scores live in VMEM registers and never hit
-# HBM at f32 width) — used by §Perf dry-run configurations.
-_SCORES_DTYPE = jnp.float32
-
-
-def set_scores_dtype(dtype) -> None:
-    global _SCORES_DTYPE
-    _SCORES_DTYPE = dtype
 
 
 def chunk_unroll(n: int):
@@ -98,14 +78,10 @@ def softcap(x: jnp.ndarray, cap: float) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 def _gqa_scores(q, k, scale):
-    """q: (B,Sq,Hkv,G,hd), k: (B,Skv,Hkv,hd) → (B,Hkv,G,Sq,Skv) scores.
-
-    The score tiles stay in ``_SCORES_DTYPE`` end-to-end through the
-    softmax chain (only the small (…,Sq) running max/sum are f32) — in
-    bf16 mode this halves every score-sized fusion boundary, matching
-    what the fused TPU flash kernel keeps out of HBM entirely."""
+    """q: (B,Sq,Hkv,G,hd), k: (B,Skv,Hkv,hd) → (B,Hkv,G,Sq,Skv) f32
+    scores."""
     s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k,
-                   preferred_element_type=_SCORES_DTYPE)
+                   preferred_element_type=jnp.float32)
     return s * jnp.asarray(scale, s.dtype)
 
 
@@ -141,10 +117,7 @@ def _attn_bias(q_idx, k_idx, *, causal, window, prefix, kv_len, valid_kv,
 
 
 def _attn_tile(qg, k_i, v_i, bias, carry, *, scale, attn_cap):
-    """One flash tile: online-softmax update of (m, l, acc).
-
-    Score-sized tensors stay in ``_SCORES_DTYPE``; the running max/sum/
-    accumulator (…,Tq[,hd]) carries stay f32."""
+    """One flash tile: online-softmax update of the f32 (m, l, acc)."""
     m_prev, l_prev, acc_prev = carry
     s = _gqa_scores(qg, k_i, scale)               # (B,Hkv,G,Tq,Tk)
     s = softcap(s, attn_cap)
@@ -212,7 +185,7 @@ def chunked_attention(
     # sliding window (hymba w=1024 at 32k: ~16× fewer tiles).  This is
     # FullBlock sparsity applied to the attention score matrix — the same
     # block-skip idea the paper applies to CIM weight tiles.
-    use_tiled = (_TILED_ATTN and causal and kv_len is None and static_q0
+    use_tiled = (causal and kv_len is None and static_q0
                  and Sq == Skv and Sq > chunk)
     if use_tiled:
         tq = chunk
@@ -317,7 +290,7 @@ def _swa_seqpar_attention(x, p, cfg, mesh, *, window: int,
     hd, Hq, Hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     G = Hq // Hkv
     W = window
-    baxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    baxes = batch_axes(mesh)
     scale = 1.0 / math.sqrt(hd)
     n_tiles = max(1, S_loc // chunk)
     tq = S_loc // n_tiles
@@ -408,9 +381,8 @@ def attention_block(
     mesh = jax.sharding.get_abstract_mesh()
     if (cache_kv is None and cross_kv is None and causal
             and isinstance(window, int) and not cfg.qk_norm
-            and prefix == 0 and not mesh.empty
-            and "model" in mesh.axis_names and mesh.shape["model"] > 1
-            and Hq % mesh.shape["model"] != 0
+            and prefix == 0 and "model" in mesh.axis_names
+            and not divides(Hq, mesh)
             and S % (mesh.shape["model"] * 1024) == 0):
         y, kc, vc = _swa_seqpar_attention(x, p, cfg, mesh, window=window)
         return y, (kc, vc)
@@ -575,27 +547,16 @@ def _moe_block_ep(x: jnp.ndarray, p: Params, cfg, mesh, baxes) -> jnp.ndarray:
     all_to_all over "model", computes its resident experts, and reverses
     the exchange — per-device expert FLOPs = global/(data·model) and the
     only collectives are two a2a's + one output all-gather per layer.
-
-    When FSDP weight sharding is on, expert weights arrive additionally
-    sharded over "data" and are all-gathered per use (their transpose is
-    a reduce-scatter, so weight grads come back ZeRO-2 style).
     """
-    from ..distributed.sharding import get_options
-    opts = get_options()
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
     M = mesh.shape["model"]
     E_loc = E // M
-    nb = 1
-    for a in baxes:
-        nb *= mesh.shape[a]
-    fsdp = opts.fsdp
 
     w_gate = p.get("w_gate")
     gated = w_gate is not None
-    # in_specs mirror spec_for_param's assignments for these leaves
-    wspec_up = P("model", None, "data") if fsdp else P("model", None, None)
-    wspec_dn = P("model", "data", None) if fsdp else P("model", None, None)
+    # mirrors spec_for_param's expert-parallel assignment of these leaves
+    wspec = P("model", None, None)
 
     def ep_body(xl, wr, wu, wd, wg):
         B_loc = xl.shape[0]
@@ -617,11 +578,6 @@ def _moe_block_ep(x: jnp.ndarray, p: Params, cfg, mesh, baxes) -> jnp.ndarray:
             eb.reshape(M, E_loc, C, D), "model", 0, 0)    # (M, E_loc, C, D)
         ex = ex.transpose(1, 0, 2, 3).reshape(E_loc, M * C, D)
 
-        if fsdp:
-            wu = jax.lax.all_gather(wu, "data", axis=2, tiled=True)
-            wd = jax.lax.all_gather(wd, "data", axis=1, tiled=True)
-            if gated:
-                wg = jax.lax.all_gather(wg, "data", axis=2, tiled=True)
         lp = {"w_up": wu, "w_down": wd}
         if gated:
             lp["w_gate"] = wg
@@ -639,11 +595,11 @@ def _moe_block_ep(x: jnp.ndarray, p: Params, cfg, mesh, baxes) -> jnp.ndarray:
         return yt.reshape(B_loc, S, D)
 
     gate_arg = w_gate if gated else jnp.zeros((), x.dtype)
-    gate_spec = wspec_up if gated else P()
+    gate_spec = wspec if gated else P()
     return jax.shard_map(
         ep_body, mesh=mesh,
         in_specs=(P(baxes, None, None), P(None, None),
-                  wspec_up, wspec_dn, gate_spec),
+                  wspec, wspec, gate_spec),
         out_specs=P(baxes, None, None),
         check_vma=False,
     )(x, p["w_router"], p["w_up"], p["w_down"], gate_arg)
@@ -654,17 +610,11 @@ def moe_block(x: jnp.ndarray, p: Params, cfg) -> jnp.ndarray:
     path on a mesh with a "model" axis that divides the expert count;
     falls back to the global-dispatch path otherwise (single device /
     smoke tests)."""
-    from ..distributed.sharding import get_options
     mesh = jax.sharding.get_abstract_mesh()
-    if (get_options().ep_shardmap and not mesh.empty
-            and "model" in mesh.axis_names
-            and cfg.n_experts % mesh.shape["model"] == 0):
-        baxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-        nb = 1
-        for a in baxes:
-            nb *= mesh.shape[a]
-        if x.shape[0] % max(nb, 1) == 0:
-            return _moe_block_ep(x, p, cfg, mesh, baxes)
+    baxes = batch_axes(mesh)
+    if (divides(cfg.n_experts, mesh)
+            and divides(x.shape[0], mesh, baxes)):
+        return _moe_block_ep(x, p, cfg, mesh, baxes)
     return _moe_block_global(x, p, cfg)
 
 

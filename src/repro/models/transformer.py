@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
-from ..distributed.sharding import batch_axes, maybe_shard
+from ..distributed.sharding import maybe_shard
 from .layers import (attention_block, chunked_attention, mlp_block,
                      moe_block, rms_norm, rope, softcap, ssm_block)
 
@@ -330,8 +330,7 @@ REMAT_POLICIES = {
     # dots — smallest footprint, most recompute (legacy default)
     "minimal": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
     # save every dot output: no matmul recompute in backward — the §Perf
-    # winner whenever peak memory has headroom (it usually does after
-    # ZeRO-1/FSDP)
+    # winner whenever peak memory has headroom
     "dots": jax.checkpoint_policies.dots_saveable,
     # save nothing (maximum recompute)
     "nothing": jax.checkpoint_policies.nothing_saveable,

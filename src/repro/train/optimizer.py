@@ -1,9 +1,8 @@
 """AdamW with global-norm clipping, implemented directly on pytrees.
 
 Written in-tree (no optax dependency) so the optimizer state layout is
-fully under our control for ZeRO-1 sharding: ``m``/``v`` mirror the
-parameter tree in f32 and receive their own PartitionSpecs (the trainer
-shards them over the "data" axis on top of the tensor-parallel specs).
+fully under our control: ``m``/``v`` mirror the parameter tree in f32
+and take the parameters' PartitionSpecs.
 """
 from __future__ import annotations
 

@@ -7,7 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.configs import cells_for, get_config
+from repro.configs import cells_for, get_config, list_archs
 from repro.configs.base import SHAPE_CELLS
 
 
@@ -52,29 +52,120 @@ def test_input_specs_shapes():
     assert sp["batch"]["prefix_embed"].shape == (256, 256, 2048)
 
 
+def _abstract_mesh(shape, axes):
+    from jax.sharding import AbstractMesh, AxisType
+    return AbstractMesh(tuple(shape), tuple(axes),
+                        axis_types=(AxisType.Auto,) * len(axes))
+
+
+_PRODUCTION_MESHES = {"single": ((16, 16), ("data", "model")),
+                      "multi": ((2, 16, 16), ("pod", "data", "model"))}
+
+
 def test_spec_for_param_divisibility_fallbacks():
-    from repro.distributed.sharding import options, spec_for_param
+    from repro.distributed.sharding import spec_for_param
     from jax.sharding import PartitionSpec as P
+    mesh = _abstract_mesh(*_PRODUCTION_MESHES["single"])
     # hymba: 25 q heads don't divide 16 → REPLICATE (never shard the
     # score-contraction head_dim — §Perf it1: hd-sharding on both sides
     # of the contraction forces score-matrix all-reduces)
-    assert spec_for_param("wq", (32, 1600, 25, 64)) == P(None, None, None, None)
-    assert spec_for_param("wq", (32, 4096, 32, 128)) == P(None, None, "model", None)
-    # legacy mode keeps the old hd fallback for A/B runs
-    with options(attn_kv_fallback="head_dim"):
-        assert spec_for_param("wq", (32, 1600, 25, 64)) == \
-            P(None, None, None, "model")
+    assert spec_for_param("wq", (32, 1600, 25, 64), mesh) == \
+        P(None, None, None, None)
+    assert spec_for_param("wq", (32, 4096, 32, 128), mesh) == \
+        P(None, None, "model", None)
     # odd vocab → d_model sharding
-    assert spec_for_param("embed", (51865, 1024)) == P(None, "model")
-    assert spec_for_param("embed", (128256, 4096)) == P("model", None)
+    assert spec_for_param("embed", (51865, 1024), mesh) == P(None, "model")
+    assert spec_for_param("embed", (128256, 4096), mesh) == P("model", None)
     # MoE experts expert-sharded
-    assert spec_for_param("w_up", (40, 16, 6144, 10752)) == \
+    assert spec_for_param("w_up", (40, 16, 6144, 10752), mesh) == \
         P(None, "model", None, None)
-    # FSDP adds a "data" axis on the largest free non-layer dim
-    with options(fsdp=True):
-        assert spec_for_param("w_up", (40, 16, 6144, 10752)) == \
-            P(None, "model", None, "data")
-        assert spec_for_param("embed", (128256, 4096)) == P("model", "data")
+
+
+def _spec_json(spec):
+    return [e if e is None or isinstance(e, str) else list(e) for e in spec]
+
+
+def _production_record(cfg, mesh):
+    """Every spec the dry-run places on ``mesh``, in the fixture's form."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import (batch_spec, cache_specs,
+                                            logits_spec, tree_specs)
+    from repro.launch.dryrun import param_struct
+    flat = jax.tree_util.tree_flatten_with_path(
+        tree_specs(param_struct(cfg), mesh),
+        is_leaf=lambda x: isinstance(x, P))[0]
+    return {
+        "params": {jax.tree_util.keystr(p): _spec_json(s) for p, s in flat},
+        "cache": {name: {k: _spec_json(v)
+                         for k, v in cache_specs(cfg, cell, mesh).items()}
+                  for name, cell in cells_for(cfg).items()
+                  if cell.kind == "decode"},
+        "batch": _spec_json(batch_spec(mesh)),
+        "logits": _spec_json(logits_spec(mesh)),
+    }
+
+
+@pytest.fixture(scope="module")
+def production_specs():
+    import json
+    from pathlib import Path
+    path = (Path(__file__).parent / "fixtures" / "sharding"
+            / "production_specs.json")
+    return json.loads(path.read_text())
+
+
+@pytest.mark.parametrize("mesh_kind", sorted(_PRODUCTION_MESHES))
+@pytest.mark.parametrize("arch", sorted(list_archs()))
+def test_production_specs_unchanged(production_specs, arch, mesh_kind):
+    """On the dry-run's two production meshes every spec is the one the
+    fixture recorded when the rule still assumed a 16×16 mesh."""
+    mesh = _abstract_mesh(*_PRODUCTION_MESHES[mesh_kind])
+    assert _production_record(get_config(arch), mesh) == \
+        production_specs[arch][mesh_kind]
+
+
+def test_spec_for_param_reads_the_mesh():
+    """On a four-chip (1, 4) mesh placement follows the mesh, and
+    attention_block's sequence-parallel choice agrees with the spec rule."""
+    import dataclasses
+    from jax.sharding import PartitionSpec as P
+    from repro.configs.base import ShapeCell
+    from repro.distributed.sharding import cache_specs, divides, tree_specs
+    from repro.launch.dryrun import param_struct
+    from repro.models.layers import attention_block
+
+    mesh = _abstract_mesh((1, 4), ("data", "model"))
+    moe = get_config("qwen3-moe-30b-a3b")
+    assert moe.n_kv_heads == 4
+    layers = tree_specs(param_struct(moe), mesh)["layers"]
+    assert layers["wk"] == layers["wv"] == P(None, None, "model", None)
+    cache = cache_specs(moe, ShapeCell("four", 512, 4, "decode"), mesh)
+    assert cache["k"] == cache["v"] == P(None, "data", None, "model", None)
+
+    hymba = get_config("hymba-1.5b")
+    assert hymba.n_heads == 25
+    wq = tree_specs(param_struct(hymba), mesh)["layers"]["wq"]
+    assert wq == P(None, None, None, None)
+
+    sds = jax.ShapeDtypeStruct
+    S, D = 4 * 1024, 64
+    for cfg in (hymba, moe):
+        cfg = dataclasses.replace(cfg, qk_norm=False)
+        Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+        p = {"wq": sds((D, Hq, hd), jnp.float32),
+             "wk": sds((D, Hkv, hd), jnp.float32),
+             "wv": sds((D, Hkv, hd), jnp.float32),
+             "wo": sds((Hq, hd, D), jnp.float32)}
+        with jax.sharding.use_abstract_mesh(mesh):
+            jaxpr = jax.make_jaxpr(
+                lambda x, p, pos, cfg=cfg: attention_block(
+                    x, p, cfg, positions=pos, window=64))(
+                sds((1, S, D), jnp.float32), p, sds((1, S), jnp.int32))
+        seqpar = "shard_map" in str(jaxpr)
+        heads_sharded = "model" in tree_specs(
+            param_struct(cfg), mesh)["layers"]["wq"]
+        assert seqpar == (not divides(Hq, mesh)) == (not heads_sharded), \
+            cfg.name
 
 
 def test_cells_for_skips():
