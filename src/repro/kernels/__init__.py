@@ -10,11 +10,12 @@ Validated in interpret mode on CPU; see tests/test_kernels.py.
 """
 from .ops import (bitserial_zero_profile, block_importance,
                   block_sparse_matmul, compress_fullblock,
-                  compress_intrablock, decompress_intrablock,
-                  flash_attention, intrablock_gather_matmul)
+                  compress_intrablock, decode_attention,
+                  decompress_intrablock, flash_attention,
+                  intrablock_gather_matmul)
 
 __all__ = [
     "bitserial_zero_profile", "block_importance", "block_sparse_matmul",
-    "compress_fullblock", "compress_intrablock", "decompress_intrablock",
-    "flash_attention", "intrablock_gather_matmul",
+    "compress_fullblock", "compress_intrablock", "decode_attention",
+    "decompress_intrablock", "flash_attention", "intrablock_gather_matmul",
 ]

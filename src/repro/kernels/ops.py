@@ -23,6 +23,9 @@ from . import ref as _ref
 from .bitserial_profile import bitserial_zero_profile_pallas
 from .block_importance import block_importance_pallas
 from .block_sparse_matmul import block_sparse_matmul_pallas
+from .decode_attention import block_size as decode_attention_block
+from .decode_attention import decode_attention_pallas
+from .decode_attention import fits as _decode_fits
 from .flash_attention import flash_attention_pallas
 from .intrablock_matmul import intrablock_gather_matmul_pallas
 
@@ -34,6 +37,9 @@ __all__ = [
     "block_importance",
     "bitserial_zero_profile",
     "flash_attention",
+    "decode_attention",
+    "decode_attention_block",
+    "resolve_decode_attention",
 ]
 
 
@@ -194,3 +200,36 @@ def flash_attention(q, k, v, *, causal: bool = True,
             qf, kf, vf, causal=causal, window=window, tile_q=tile_q,
             tile_k=tile_k, interpret=(impl == "pallas_interpret"))
     return of.reshape(B, Hq, Sq, hd).transpose(0, 2, 1, 3)
+
+
+def resolve_decode_attention(impl: str, k) -> str:
+    """``impl`` for :func:`decode_attention` over stacks like ``k``.
+    ``"auto"`` takes the kernel on a TPU, outside a multi-device mesh
+    (the kernel reads each slot whole from one device's HBM, so a
+    sharded cache stays on the XLA path) and where the kernel tiles the
+    stacks' head layout (``decode_attention.fits``)."""
+    if impl != "auto":
+        return impl
+    mesh = jax.sharding.get_abstract_mesh()
+    one_device = mesh.empty or mesh.size == 1
+    ok = _on_tpu() and one_device and _decode_fits(k.shape, k.dtype)
+    return "pallas" if ok else "ref"
+
+
+def decode_attention(q, k, v, layer, lengths, window=None, *,
+                     attn_cap: float = 0.0, impl: str = "auto"):
+    """Single-query decode attention over the stacked serving cache.
+
+    q: (B, Hq, hd); k/v: every layer's (L, B, Smax, Hkv, hd) stack;
+    ``layer``: this layer's index; ``lengths``: (B,) valid positions per
+    slot, the new row included (0 = a free slot, which reads nothing and
+    returns zeros); ``window``: None, int or traced scalar.  The kernel
+    fetches only each slot's live blocks; ``"ref"`` is the XLA path.
+    """
+    impl = resolve_decode_attention(impl, k)
+    if impl == "ref":
+        return _ref.decode_attention_ref(q, k, v, layer, lengths, window,
+                                         attn_cap=attn_cap)
+    return decode_attention_pallas(
+        q, k, v, layer, lengths, window, attn_cap=attn_cap,
+        interpret=(impl == "pallas_interpret"))

@@ -17,6 +17,7 @@ __all__ = [
     "block_importance_ref",
     "bitserial_zero_profile_ref",
     "flash_attention_ref",
+    "decode_attention_ref",
 ]
 
 
@@ -43,6 +44,29 @@ def flash_attention_ref(
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
+
+
+def decode_attention_ref(q, k, v, layer, lengths, window=None, *,
+                         attn_cap: float = 0.0) -> jnp.ndarray:
+    """Single-query decode attention as the model's XLA path runs it:
+    this layer's whole (B, Smax, Hkv, hd) block out of the stacked
+    (L, B, Smax, Hkv, hd) cache, then ``chunked_attention`` in ONE chunk
+    spanning the cache, with positions from each slot's length on (and
+    before its window) masked.  q: (B, Hq, hd); lengths: (B,).
+
+    One chunk keeps the scores (B, H, 1, Smax) small, and XLA shards the
+    sequence dim cleanly (flash-decode: partial softmax per shard + small
+    cross-shard reductions), whereas a chunk scan would fight the
+    sequence sharding and replicate compute.
+    """
+    # models import this package, so bind the model's attention late
+    from ..models.layers import chunked_attention
+    k = jax.lax.dynamic_index_in_dim(k, layer, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+    out = chunked_attention(q[:, None], k, v, causal=True, window=window,
+                            q_offset=jnp.asarray(lengths) - 1,
+                            attn_cap=attn_cap, chunk=k.shape[1])
+    return out[:, 0]
 
 
 def block_sparse_matmul_ref(

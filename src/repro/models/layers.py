@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import batch_axes, divides, maybe_shard
+from ..kernels import ops
 
 Params = Dict[str, Any]
 
@@ -366,10 +367,12 @@ def attention_block(
     * training/prefill: ``cache_kv=None`` → attends within ``x``.
     * decode: ``cache_kv=(K, V)`` are every layer's buffers stacked
       (L, B, Smax, Hkv, hd), ``layer`` this layer's index and
-      ``cache_len`` the current length; the new K/V row is written at
-      (``layer``, slot, ``cache_len``), attention spans the valid prefix
-      of this layer's block, and the updated stacks are returned.  Only
-      the new rows change, so a donated stack is updated in place.
+      ``cache_len`` the current length, per slot or uniform (-1 marks a
+      free slot); the new K/V row is written at (``layer``, slot,
+      ``cache_len``), ``kernels.ops.decode_attention`` attends over each
+      slot's valid positions of this layer, and the updated stacks are
+      returned.  Only the new rows change, so a donated stack is updated
+      in place.
     * cross-attention (whisper decoder): ``cross_kv`` precomputed from
       the encoder; no cache update.
     """
@@ -417,25 +420,20 @@ def attention_block(
                 V = jax.lax.dynamic_update_slice(V, v[None].astype(V.dtype),
                                                  start)
             else:
-                # per-slot positions (serving): scatter one row per batch
+                # per-slot positions (serving): scatter one row per slot;
+                # a free slot (pos -1) is sent out of range and dropped
                 bidx = jnp.arange(K.shape[1])
-                K = K.at[layer, bidx, pos].set(k[:, 0])
-                V = V.at[layer, bidx, pos].set(v[:, 0])
+                row = jnp.where(pos < 0, K.shape[2], pos)
+                K = K.at[layer, bidx, row].set(k[:, 0], mode="drop")
+                V = V.at[layer, bidx, row].set(v[:, 0], mode="drop")
             new_cache = (K, V)
-            # read this layer's block after the write: the new row is
-            # attended
-            K = jax.lax.dynamic_index_in_dim(K, layer, keepdims=False)
-            V = jax.lax.dynamic_index_in_dim(V, layer, keepdims=False)
-            # q lives at absolute position cache_len; the causal mask also
-            # masks the unwritten cache tail (k_idx > cache_len + S - 1).
-            # Single-query decode uses ONE chunk spanning the whole cache:
-            # scores are only (B,H,1,Skv), and XLA shards the sequence dim
-            # cleanly (flash-decode: partial softmax per shard + small
-            # cross-shard reductions), whereas a chunk scan would fight
-            # the sequence sharding and replicate compute.
-            out = chunked_attention(
-                q, K, V, causal=True, window=window, q_offset=cache_len,
-                attn_cap=cfg.attn_softcap, chunk=K.shape[1])
+            # attend after the write, so the new row is attended: each
+            # slot's first pos + 1 positions (none for a free slot), read
+            # in place in the stacks
+            lengths = jnp.broadcast_to(pos + 1, (B,))
+            out = ops.decode_attention(
+                q[:, 0], K, V, layer, lengths, window,
+                attn_cap=cfg.attn_softcap)[:, None]
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"]).astype(x.dtype)
     return y, new_cache
 

@@ -462,7 +462,7 @@ def decode_step(
         tokens = tokens[:, None]
     x = _embed(params, tokens, cfg)
     B = x.shape[0]
-    pos = jnp.asarray(cache["pos"])          # scalar, or (B,) per-slot
+    pos = jnp.asarray(cache["pos"])   # scalar, or (B,) per-slot; -1 = free
     positions = jnp.broadcast_to(
         pos if pos.ndim == 0 else pos[:, None], (B, 1))
     flags = layer_flags(cfg)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..configs.base import ArchConfig
+from ..kernels import ops
 from ..models.transformer import STATE_KEYS, decode_step, init_cache, prefill
 from ..obs.metrics import ServeMetrics
 from ..runtime import annotate_spans
@@ -105,6 +106,13 @@ class ServeEngine:
             # into it in place
             return decode_step(p, t, cfg, c)
 
+        # decode attention fetches each live slot's K/V in blocks of this
+        # many positions; the XLA path (None) reads every slot whole
+        k = self.cache.get("k")
+        self._kv_block = (ops.decode_attention_block(max_len)
+                          if k is not None
+                          and ops.resolve_decode_attention("auto", k) != "ref"
+                          else None)
         self._prefill = jax.jit(serve_prefill, donate_argnums=2)
         self._prefilled_lengths: Set[int] = set()
         self._decode = jax.jit(serve_decode, donate_argnums=2)
@@ -210,9 +218,14 @@ class ServeEngine:
     def _decode_active(self, active: List[int]) -> int:
         """One decode step for the ``active`` slots; returns how many
         requests it completed."""
-        with obs.span("serve.decode", active=len(active)):
-            # per-slot positions: each slot decodes at its own cache length
-            self.cache["pos"] = jnp.asarray(self.slot_pos, jnp.int32)
+        with obs.span("serve.decode", active=len(active)) as sp:
+            # per-slot positions: each slot decodes at its own cache
+            # length; a free slot is marked -1, so it reads and writes no
+            # K/V
+            pos = np.full(self.slots, -1, np.int32)
+            pos[active] = self.slot_pos[active]
+            sp.set(kv_read_share=self._kv_read_share(pos))
+            self.cache["pos"] = jnp.asarray(pos)
             tokens = jnp.asarray(self._last_tokens)
             logits, self.cache = self._decode(self.params, tokens, self.cache)
             with obs.span("serve.decode.wait"):
@@ -239,6 +252,17 @@ class ServeEngine:
                     self.slot_req[s] = None
                     self.metrics.on_expire(queued=False)
         return completed
+
+    def _kv_read_share(self, pos: np.ndarray) -> float:
+        """Share of the K/V cache the decode attention reads: each live
+        slot's length (``pos + 1``) rounded up to the kernel's block,
+        over slots x max_len (1.0 on the XLA path, which reads every
+        slot whole).  A sliding window reads less than this."""
+        if self._kv_block is None:
+            return 1.0
+        blk = self._kv_block
+        fetched = -(-(pos + 1) // blk) * blk
+        return float(fetched.sum()) / (self.slots * self.max_len)
 
     def run(self) -> None:
         """Drain queue + slots; leaves this call's deltas in

@@ -161,3 +161,49 @@ def test_flash_attention_matches_model_attention():
     y_model = chunked_attention(q, k, v, causal=True, window=64, chunk=64)
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_model),
                                atol=5e-5)
+
+
+# ---------------------------------------------------------------------------
+# ragged decode attention kernel
+# ---------------------------------------------------------------------------
+
+_BIG = 1 << 30
+
+
+@pytest.mark.parametrize("layer,G,window,cap,dtype", [
+    (0, 4, None, 0.0, jnp.bfloat16),
+    (1, 4, 100, 0.0, jnp.bfloat16),        # window binds
+    (2, 4, _BIG, 50.0, jnp.bfloat16),      # window does not bind
+    (2, 1, None, 50.0, np.float32),
+    (0, 1, 100, 0.0, np.float32),
+    (1, 4, _BIG, 50.0, np.float32),
+    (1, 1, 100, 50.0, jnp.bfloat16),
+], ids=["first-g4-bf16", "mid-g4-window", "last-g4-wide-cap",
+        "last-g1-cap-f32", "first-g1-window-f32", "mid-g4-wide-cap-f32",
+        "mid-g1-window-cap-bf16"])
+@pytest.mark.parametrize("lengths", [(0, 1, 127, 128, 129, 256), 200],
+                         ids=["per-slot", "scalar-pos"])
+def test_decode_attention_sweep(layer, G, window, cap, dtype, lengths):
+    """Kernel (interpret mode) ≡ the XLA decode path (this layer's block
+    of the stacks + chunked_attention), with the window traced as the
+    layer scan passes it; slots of length 0 are left out."""
+    import jax
+    from repro.kernels import ops
+    L, B, Smax, Hkv, hd = 3, 6, 256, 2, 128
+    q = jnp.asarray(RNG.normal(size=(B, G * Hkv, hd)), dtype)
+    k = jnp.asarray(RNG.normal(size=(L, B, Smax, Hkv, hd)), dtype)
+    v = jnp.asarray(RNG.normal(size=(L, B, Smax, Hkv, hd)), dtype)
+    n = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+
+    def attend(impl):
+        fn = jax.jit(lambda q, k, v, layer, n, w: ops.decode_attention(
+            q, k, v, layer, n, None if window is None else w,
+            attn_cap=cap, impl=impl))
+        return np.asarray(fn(q, k, v, jnp.int32(layer), n,
+                             jnp.int32(window or 0)), np.float32)
+
+    y, ref = attend("pallas_interpret"), attend("ref")
+    live = np.asarray(n) > 0
+    assert np.all(np.isfinite(y))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(y[live], ref[live], atol=tol)

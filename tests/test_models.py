@@ -60,10 +60,23 @@ def test_train_step(arch):
     assert delta > 0
 
 
-@pytest.mark.parametrize("arch", ["llama3-8b", "gemma2-9b", "mamba2-130m",
-                                  "hymba-1.5b", "whisper-medium",
-                                  "qwen3-moe-30b-a3b", "paligemma-3b"])
-def test_decode_matches_forward(arch):
+_DECODE_ARCHS = ["llama3-8b", "gemma2-9b", "mamba2-130m", "hymba-1.5b",
+                 "whisper-medium", "qwen3-moe-30b-a3b", "paligemma-3b"]
+
+
+@pytest.mark.parametrize("arch,impl", [
+    *[pytest.param(a, None, id=a) for a in _DECODE_ARCHS],
+    *[pytest.param(a, "pallas_interpret", id=f"{a}-kernel")
+      for a in _DECODE_ARCHS if get_config(a).attention != "none"],
+])
+def test_decode_matches_forward(arch, impl, monkeypatch):
+    """One decode step after a prefill gives the logits a full forward
+    gives at that position; ``impl`` forces the decode attention kernel
+    (interpret mode) where the CPU would take the XLA path."""
+    from repro.kernels import ops
+    if impl is not None:
+        monkeypatch.setattr(ops, "resolve_decode_attention",
+                            lambda *_: impl)
     cfg = get_config(arch).reduced()
     key = jax.random.PRNGKey(1)
     params = init_params(cfg, key, dtype=jnp.float32)
@@ -83,6 +96,35 @@ def test_decode_matches_forward(arch):
     ref = full[:, pfx + S, :]
     np.testing.assert_allclose(np.asarray(lg), np.asarray(ref),
                                atol=5e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("impl", [None, "pallas_interpret"],
+                         ids=["xla", "kernel"])
+def test_decode_step_free_slot_reads_and_writes_nothing(impl, monkeypatch):
+    """A slot at position -1 (free) gets no K/V row written, and the
+    live slot's logits are the ones it gets beside a busy slot."""
+    from repro.kernels import ops
+    if impl is not None:
+        monkeypatch.setattr(ops, "resolve_decode_attention",
+                            lambda *_: impl)
+    cfg = get_config("llama3-8b").reduced()
+    params = init_params(cfg, jax.random.PRNGKey(4), dtype=jnp.float32)
+    cache = init_cache(cfg, 2, 32, dtype=jnp.float32)
+    for i, key in enumerate(("k", "v")):
+        cache[key] = jax.random.normal(jax.random.PRNGKey(5 + i),
+                                       cache[key].shape)
+    tokens = jnp.array([3, 5], jnp.int32)
+    step = jax.jit(lambda p, t, c: decode_step(p, t, cfg, c))
+    lg_busy, _ = step(params, tokens, dict(cache, pos=jnp.array([9, 20])))
+    lg, new = step(params, tokens, dict(cache, pos=jnp.array([9, -1])))
+    for key in ("k", "v"):
+        old, upd = np.asarray(cache[key]), np.asarray(new[key])
+        np.testing.assert_array_equal(upd[:, 1], old[:, 1])
+        np.testing.assert_array_equal(np.delete(upd[:, 0], 9, axis=1),
+                                      np.delete(old[:, 0], 9, axis=1))
+    np.testing.assert_allclose(np.asarray(lg[0]), np.asarray(lg_busy[0]),
+                               atol=1e-5, rtol=1e-5)
+    assert bool(jnp.all(jnp.isfinite(lg)))
 
 
 def test_ssd_gradient_finite_over_a_full_chunk():
@@ -178,16 +220,27 @@ def test_decode_step_named_scopes(arch, scopes, monkeypatch):
                                                            cache)))
 
 
-@pytest.mark.parametrize("arch,per_slot", [
-    ("llama3-8b", True), ("llama3-8b", False), ("mamba2-130m", True),
-    ("hymba-1.5b", True), ("gemma2-9b", True), ("whisper-medium", True),
+@pytest.mark.parametrize("arch,per_slot,impl", [
+    ("llama3-8b", True, None), ("llama3-8b", False, None),
+    ("mamba2-130m", True, None), ("hymba-1.5b", True, None),
+    ("gemma2-9b", True, None), ("whisper-medium", True, None),
+    ("hymba-1.5b", True, "pallas_interpret"),
+    ("gemma2-9b", True, "pallas_interpret"),
 ], ids=["dense-per-slot-pos", "dense-scalar-pos", "mamba2-130m",
-        "hymba-1.5b", "gemma2-9b", "whisper-medium"])
-def test_decode_step_writes_only_each_slots_new_row(arch, per_slot):
+        "hymba-1.5b", "gemma2-9b", "whisper-medium", "hymba-1.5b-kernel",
+        "gemma2-9b-kernel"])
+def test_decode_step_writes_only_each_slots_new_row(arch, per_slot, impl,
+                                                    monkeypatch):
     """From a cache whose slots were prefilled to different lengths,
     each decode step changes only each slot's new K/V row (every other
     row is bit-identical to its input), leaves the cross K/V untouched,
-    and gives the logits a full forward gives at that position."""
+    and gives the logits a full forward gives at that position; with
+    ``impl`` the decode attention kernel (interpret mode) reads the
+    sliding windows."""
+    from repro.kernels import ops
+    if impl is not None:
+        monkeypatch.setattr(ops, "resolve_decode_attention",
+                            lambda *_: impl)
     cfg = get_config(arch).reduced()
     params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
     # past the reduced window (32), so sliding masks take effect

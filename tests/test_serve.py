@@ -136,6 +136,64 @@ def test_prefill_traces_once_per_prompt_length(params, monkeypatch,
                                              r.max_new_tokens)
 
 
+def test_free_slots_read_no_kv_and_the_kernel_serves_the_same_tokens(
+        params, monkeypatch, tmp_path):
+    """Each decode hands a free slot position -1 (length 0: no K/V read
+    or written); ``serve.decode`` reports ``kv_read_share``, the live
+    slots' lengths rounded up to the kernel's block over slots x
+    max_len (1.0 on the XLA path); and the engine serves the same
+    tokens through the XLA path and the interpreted decode kernel."""
+    from repro import obs
+    from repro.kernels import ops
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(0, CFG.vocab_size, size=n).astype(np.int32)
+               for n in (5, 40, 9)]
+    slots, max_len = 3, 96
+    blk = ops.decode_attention_block(max_len)
+    assert blk == 32
+
+    def serve(impl):
+        monkeypatch.setattr(ops, "resolve_decode_attention",
+                            lambda *_: impl)
+        engine = ServeEngine(CFG, params, slots=slots, max_len=max_len)
+        calls, real = [], engine._decode
+
+        def spy(p, t, c):
+            calls.append((np.asarray(c["pos"]),
+                          [r is not None for r in engine.slot_req]))
+            return real(p, t, c)
+
+        engine._decode = spy
+        reqs = [Request(prompt=p, max_new_tokens=n)
+                for p, n in zip(prompts, (4, 7, 3))]
+        engine.submit(reqs[0])
+        engine.submit(reqs[1])
+        engine.step()
+        engine.submit(reqs[2])
+        with obs.enabled(tmp_path / impl):
+            engine.run()
+            spans = obs.read_events(tmp_path / impl, "serve.decode")
+        shares = [ev["attrs"]["kv_read_share"] for ev in spans]
+        return reqs, calls, shares
+
+    outs = {}
+    for impl in ("ref", "pallas_interpret"):
+        reqs, calls, shares = serve(impl)
+        assert all(r.done for r in reqs)
+        outs[impl] = [r.output for r in reqs]
+        assert any(not all(busy) for _, busy in calls)
+        for pos, busy in calls:
+            assert np.all((pos == -1) == ~np.asarray(busy)), (pos, busy)
+        want = [float((-(-(pos + 1) // blk) * blk).sum())
+                / (slots * max_len) for pos, _ in calls[-len(shares):]]
+        assert shares == (want if impl != "ref" else [1.0] * len(shares))
+    assert 0 < min(want) and max(want) < 1
+    assert outs["ref"] == outs["pallas_interpret"]
+    for out, p, r in zip(outs["ref"], prompts, reqs):
+        assert out == _served_reference(CFG, params, p, r.max_new_tokens,
+                                        max_len)
+
+
 def test_more_requests_than_slots(params):
     rng = np.random.default_rng(1)
     engine = ServeEngine(CFG, params, slots=2, max_len=48)

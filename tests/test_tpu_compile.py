@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_config
+from repro.configs import get_config, list_archs
 from repro.kernels import ops
 
 K, N = 2560, 9728          # qwen3-4b d_model, d_ff
@@ -64,6 +64,35 @@ def test_flash_attention_compiles(one_chip, skv):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("arch", [a for a in list_archs()
+                                  if get_config(a).attention != "none"])
+def test_decode_attention_compiles_where_it_fits(one_chip, arch,
+                                                 monkeypatch):
+    """On a TPU, every config whose bf16 K/V head layout ``fits`` the
+    decode kernel takes it, and the TPU compiler takes the kernel at the
+    config's published heads, head dim and softcap, with a traced
+    window; the rest stay on the XLA path.  qwen3-4b must fit."""
+    from repro.kernels.decode_attention import fits
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = get_config(arch)
+    Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    L, B, S = 2, 4, 1024
+    stack = _spec(one_chip, (L, B, S, Hkv, hd), jnp.bfloat16)
+    fit = fits(stack.shape, stack.dtype)
+    assert fit or arch != "qwen3-4b"
+    assert ops.resolve_decode_attention("auto", stack) == \
+        ("pallas" if fit else "ref")
+    if not fit:
+        return
+    compiled = _compile(
+        lambda q, k, v, layer, n, w: ops.decode_attention(
+            q, k, v, layer, n, w, attn_cap=cfg.attn_softcap, impl="pallas"),
+        _spec(one_chip, (B, Hq, hd), jnp.bfloat16), stack, stack,
+        _spec(one_chip, (), jnp.int32), _spec(one_chip, (B,), jnp.int32),
+        _spec(one_chip, (), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def _block_sparse(s):
     gn, L = N // 128, 10
     return (lambda x, w, i: ops.block_sparse_matmul(x, w, i, impl="pallas"),
@@ -99,10 +128,15 @@ def test_sparse_kernel_compiles(one_chip, build):
     assert "tpu_custom_call" in _compile(fn, *args).as_text()
 
 
-def test_qwen3_4b_decode_step_compiles(one_chip):
+def test_qwen3_4b_decode_step_compiles(one_chip, monkeypatch):
+    """The two-layer qwen3-4b decode step at the chat cell's shapes (16
+    slots x 1024, bf16 cache), on the path a TPU takes, runs attention
+    in the decode kernel (a ``tpu_custom_call``) and slices no layer's
+    whole (16, 1024, 8, 128) K/V block out of the stacks."""
     from repro.models.transformer import decode_step, init_cache, init_params
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     cfg = dataclasses.replace(get_config("qwen3-4b"), n_layers=2)
-    slots, max_len = 4, 1024
+    slots, max_len = 16, 1024
 
     def on_chip(tree):
         return jax.tree.map(
@@ -111,7 +145,7 @@ def test_qwen3_4b_decode_step_compiles(one_chip):
     params = on_chip(jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
     cache = on_chip(jax.eval_shape(
-        lambda: init_cache(cfg, slots, max_len, dtype=jnp.float32)))
+        lambda: init_cache(cfg, slots, max_len, dtype=jnp.bfloat16)))
     cache["pos"] = _spec(one_chip, (slots,), jnp.int32)
     tokens = _spec(one_chip, (slots,), jnp.int32)
     step = jax.jit(lambda p, t, c: decode_step(p, t, cfg, c))
@@ -119,7 +153,15 @@ def test_qwen3_4b_decode_step_compiles(one_chip):
     logits, new_cache = lowered.out_info
     assert logits.shape == (slots, cfg.vocab_size)
     assert new_cache["k"].shape == (2, slots, max_len, HKV, HD)
-    assert lowered.compile().memory_analysis() is not None
+    compiled = lowered.compile()
+    assert compiled.memory_analysis() is not None
+    hlo = compiled.as_text()
+    assert re.search(r"%decode_attention[\w.]* = \S+ custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', hlo)
+    block = f"{slots},{max_len},{HKV},{HD}]"
+    assert not [line for line in hlo.splitlines()
+                if "dynamic-slice(" in line and block in line.split("=")[1]
+                .split("dynamic-slice(")[0]]
 
 
 _INSTR = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$")
@@ -147,7 +189,7 @@ def _producers(hlo, shape):
     return [(op, roots.get(c) if c else None) for op, c in found]
 
 
-def test_engine_decode_writes_cache_in_place(one_chip):
+def test_engine_decode_writes_cache_in_place(one_chip, monkeypatch):
     """The engine's decode program at qwen3-4b widths (16 slots x 1024,
     bf16 cache) aliases the donated K/V stacks to its output, needs no
     temporary the size of a layer's block, and writes the stacks only by
@@ -157,6 +199,7 @@ def test_engine_decode_writes_cache_in_place(one_chip):
     from repro.serve.engine import ServeEngine
     L, slots, max_len = 2, 16, 1024
     cfg = dataclasses.replace(get_config("qwen3-4b"), n_layers=L)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # the chip's path
 
     def on_chip(tree):
         return jax.tree.map(
